@@ -8,20 +8,18 @@ does not), plus the acceptance row — a sharded n=9216 solve past
 ``ONE_HOT_NODE_LIMIT`` running PER-SHARD NODE BLOCKINGS on the pallas
 backend, cross-checked against the sharded segment solve.
 
-Device counts must be fixed before jax initializes, so ``run()`` spawns
-ONE SUBPROCESS PER DEVICE COUNT with
-``XLA_FLAGS=--xla_force_host_platform_device_count=D`` re-running this
-module in child mode; children print JSON rows on stdout.  CPU caveat
-(same as bench_kernels): the virtual devices share one host and pallas
-runs in interpret mode, so these rows track correctness-adjacent
-latency trends and collective overhead, NOT TPU speedups — on a real
-mesh the same harness times the real thing.
+Everything runs in ONE process (a TPU chip admits one process): each
+device count ``d`` builds its meshes from the first ``d`` of
+``jax.devices()``, and counts above what the process sees are skipped.
+On the CPU the caller provides the devices —
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``, as
+``scripts/ci.sh`` sets it.  CPU caveat (same as bench_kernels): the
+virtual devices share one host and pallas runs in interpret mode, so
+these rows track correctness-adjacent latency trends and collective
+overhead, NOT TPU speedups.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
 
 N = 9216  # past backend.ONE_HOT_NODE_LIMIT => node-blocked layouts
@@ -38,9 +36,9 @@ def _graph():
     return g
 
 
-def _child(num_devices: int) -> list:
-    """Runs inside the XLA_FLAGS subprocess; returns (name, us, derived)
-    rows for this device count."""
+def _rows_for(num_devices: int, top: bool) -> list:
+    """(name, us, derived) rows on the first ``num_devices`` devices;
+    ``top`` adds the sharded pallas acceptance row."""
     import time
 
     import jax
@@ -54,10 +52,8 @@ def _child(num_devices: int) -> list:
     from repro.core.series import limit_neg_exp
     from repro.stream.service import ServiceConfig, StreamingService
 
-    assert jax.device_count() == num_devices, (
-        jax.device_count(), num_devices)
     d = num_devices
-    mesh = default_edge_mesh()
+    mesh = default_edge_mesh(max_shards=d)
     g = _graph()
     rows = []
 
@@ -92,7 +88,7 @@ def _child(num_devices: int) -> list:
     from repro.core import program
 
     mmesh = jax.sharding.Mesh(
-        np.array(jax.devices()).reshape(1, d), ("data", "model"))
+        np.array(jax.devices()[:d]).reshape(1, d), ("data", "model"))
     mb = backend_mod.build_model_sharded_blocking(
         np.asarray(g.src), np.asarray(g.dst), np.asarray(g.weight),
         N, d, block_n=512)
@@ -115,7 +111,7 @@ def _child(num_devices: int) -> list:
 
     # --- acceptance row: sharded node-blocked pallas solve ------------
     # (only at the top device count — interpret-mode pallas is slow)
-    if d == max(DEVICE_COUNTS):
+    if top:
         rho = float(lap.spectral_radius_upper_bound(g))
         s = limit_neg_exp(DEGREE, scale=8.0 / rho)
         cfg = solvers.SolverConfig(
@@ -144,34 +140,19 @@ def _child(num_devices: int) -> list:
 
 
 def run():
-    """Parent: spawn one child per device count, collect rows, write
+    """Rows for every device count this process can hold; writes
     BENCH_distributed.json."""
+    import jax
+
     from benchmarks.common import write_bench_json
 
-    here = os.path.abspath(__file__)
-    root = os.path.dirname(os.path.dirname(here))
+    counts = [d for d in DEVICE_COUNTS if d <= jax.device_count()]
     rows = []
     weak = {}
-    for d in DEVICE_COUNTS:
-        env = dict(os.environ)
-        # forced flag LAST: XLA parses duplicate flags last-wins, so an
-        # inherited device-count flag (e.g. the distributed lane's 8)
-        # must not override this child's count
-        env["XLA_FLAGS"] = (
-            (env["XLA_FLAGS"] + " " if env.get("XLA_FLAGS") else "")
-            + f"--xla_force_host_platform_device_count={d}")
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(root, "src"), root]
-            + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-        proc = subprocess.run(
-            [sys.executable, here, "--child", str(d)],
-            capture_output=True, text=True, env=env, cwd=root)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"bench_distributed child d={d} failed:\n{proc.stderr[-2000:]}")
-        child_rows = json.loads(proc.stdout.strip().splitlines()[-1])
-        rows.extend(tuple(r) for r in child_rows)
-        for name, us, derived in child_rows:
+    for d in counts:
+        d_rows = _rows_for(d, top=d == counts[-1])
+        rows.extend(d_rows)
+        for name, us, derived in d_rows:
             if name.startswith(f"distributed/tick_warm_n{N}_d"):
                 weak[f"tick_warm_us_d{d}"] = us
             if name.startswith(f"distributed/matvec_n{N}_d"):
@@ -182,7 +163,7 @@ def run():
     write_bench_json("distributed", rows, extra={
         "weak_scaling": {
             "n": N,
-            "device_counts": list(DEVICE_COUNTS),
+            "device_counts": counts,
             **weak,
         },
     })
@@ -190,8 +171,8 @@ def run():
 
 
 if __name__ == "__main__":
-    if len(sys.argv) >= 3 and sys.argv[1] == "--child":
-        print(json.dumps(_child(int(sys.argv[2]))))
-    else:
-        for r in run():
-            print(",".join(str(x) for x in r))
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    for r in run():
+        print(",".join(str(x) for x in r))

@@ -240,11 +240,12 @@ def http_smoke(p99_budget_s: float = 3.0) -> int:
             with urllib.request.urlopen(r, timeout=30) as resp:
                 return json.loads(resp.read())
 
-        g, _ = graphs.sbm_graph(60, 3, p_in=0.4, p_out=0.02, seed=0)
-        edges = np.stack([np.asarray(g.src), np.asarray(g.dst)], 1).tolist()
+        # numpy only: the child owns the device, so this process must
+        # not touch JAX (on a TPU host the chip admits one process)
+        edges, _ = graphs.sbm_edges(60, 3, p_in=0.4, p_out=0.02, seed=0)
         req("/v1/sessions/smoke", "POST",
-            {"edges": edges, "num_nodes": 60, "num_clusters": 3,
-             "weights": np.asarray(g.weight).tolist()})
+            {"edges": edges.tolist(), "num_nodes": 60, "num_clusters": 3,
+             "weights": [1.0] * len(edges)})
         # warm before measuring: wait out the initial convergence (tick
         # programs + probes compile here) and run one labels query (the
         # k-means labeller compiles there) so the gate scores the
@@ -306,6 +307,9 @@ if __name__ == "__main__":
     args = ap.parse_args()
     if args.http_smoke:
         sys.exit(http_smoke())
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, us, derived in run():
         print(f"{name},{us},{derived}")

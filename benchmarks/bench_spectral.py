@@ -180,5 +180,8 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for name, us, derived in run():
         print(f"{name},{us:.0f},{derived}")

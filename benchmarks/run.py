@@ -63,6 +63,9 @@ def main(argv=None) -> None:
         help="comma-separated module tags to run (e.g. 'stream,spectral')")
     args = parser.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_baselines, bench_cliques, bench_distributed,
                             bench_kernels, bench_linkpred, bench_mdp,
                             bench_serve, bench_series_degree, bench_spectral,

@@ -1,106 +1,39 @@
-"""jax version-compatibility shims.
+"""Mesh and ``shard_map`` helpers on the installed jax (0.9).
 
-The repo targets a range of jax releases.  ``shard_map`` in particular has
-moved twice:
-
-  * jax < ~0.6:  ``jax.experimental.shard_map.shard_map`` with a
-    ``check_rep=`` kwarg;
-  * newer jax:   top-level ``jax.shard_map`` with the kwarg renamed to
-    ``check_vma=``.
-
-``from repro.compat import shard_map`` works on both: it resolves the
-import at module load and translates ``check_vma``/``check_rep`` to
-whatever the installed jax accepts.
+The names below are the repo's single import point for the sharding
+API: ``shard_map`` (``check_vma=``), ``make_mesh`` with explicit Auto
+axis types, the ambient-mesh context ``set_mesh`` and its reader
+``get_abstract_mesh``, plus :func:`default_edge_mesh`.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
-try:  # newer jax exposes shard_map at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax <= 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f=None, **kwargs):
-    """Version-agnostic ``shard_map``; usable directly or via
-    ``functools.partial(shard_map, mesh=..., ...)`` as a decorator."""
-    if "check_vma" in kwargs and "check_vma" not in _PARAMS:
-        val = kwargs.pop("check_vma")
-        if "check_rep" in _PARAMS:
-            kwargs["check_rep"] = val
-    elif "check_rep" in kwargs and "check_rep" not in _PARAMS:
-        val = kwargs.pop("check_rep")
-        if "check_vma" in _PARAMS:
-            kwargs["check_vma"] = val
-    if f is None:
-        return functools.partial(shard_map, **kwargs)
-    return _shard_map(f, **kwargs)
+shard_map = jax.shard_map
+set_mesh = jax.set_mesh
+get_abstract_mesh = jax.sharding.get_abstract_mesh
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    ``jax.sharding.AxisType`` only exists on newer jax (explicit-sharding
-    releases); older jax meshes are implicitly Auto, so the kwarg is
-    simply dropped there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis typed Auto."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def default_edge_mesh(max_shards: int | None = None,
                       axis_names=("data", "model")):
-    """The ("data", "model") edge-sharding mesh over all local devices.
+    """The ("data", "model") edge-sharding mesh over the first
+    ``max_shards`` local devices (all of them by default).
 
     Every edge-parallel entry point in this repo (`core.distributed`,
     `stream.sharded`, the distributed test lane and benchmarks) shards
     edges over "data"; this helper builds that mesh from however many
     devices the process sees — 1 in plain tier-1 runs, 8 under the CI
-    lane's ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — so
-    call sites don't hand-roll device reshapes per jax version.
+    lane's ``XLA_FLAGS=--xla_force_host_platform_device_count=8``, 4 on
+    a four-chip TPU host.
     """
-    import numpy as np
-    from jax.sharding import Mesh
-
     devs = jax.devices()
     n = len(devs) if max_shards is None else min(len(devs), max_shards)
     return Mesh(np.array(devs[:n]).reshape(n, 1), axis_names)
-
-
-def set_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    Newer jax: ``jax.set_mesh(mesh)``.  Older jax: the Mesh object is
-    itself the context manager (``with mesh:``), tracked in thread
-    resources — which is exactly where :func:`get_abstract_mesh` falls
-    back to reading.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
-
-
-def get_abstract_mesh():
-    """The mesh currently in context (``with mesh:`` / ``use_mesh``).
-
-    ``jax.sharding.get_abstract_mesh`` only exists on newer jax; older
-    releases track the context mesh in thread resources.  Both return an
-    object with ``.empty``, ``.axis_names`` and ``.axis_sizes``.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax._src import mesh as _mesh_lib
-
-    return _mesh_lib.thread_resources.env.physical_mesh

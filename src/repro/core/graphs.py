@@ -107,6 +107,20 @@ def sbm_graph(
     seed: int = 0,
 ):
     """Stochastic block model (Holland et al. 1983).  Returns (EdgeList, labels)."""
+    edges, labels = sbm_edges(num_nodes, num_blocks, p_in, p_out, seed)
+    return make_edge_list(edges, num_nodes), labels
+
+
+def sbm_edges(
+    num_nodes: int,
+    num_blocks: int,
+    p_in: float = 0.5,
+    p_out: float = 0.01,
+    seed: int = 0,
+):
+    """:func:`sbm_graph` as host arrays: ((E, 2) int32 edges, labels).
+    Pure numpy, so a process that must stay off the device can build
+    the graph."""
     rng = np.random.default_rng(seed)
     labels = np.sort(rng.integers(0, num_blocks, size=num_nodes)).astype(np.int32)
     iu = np.triu_indices(num_nodes, k=1)
@@ -123,7 +137,7 @@ def sbm_graph(
         extra.append((min(u, v), max(u, v)))
     if extra:
         edges = np.concatenate([edges, np.asarray(extra, np.int32)], axis=0)
-    return make_edge_list(edges, num_nodes), labels
+    return edges, labels
 
 
 def sparse_sbm_graph(
@@ -132,6 +146,7 @@ def sparse_sbm_graph(
     avg_degree_in: float = 8.0,
     avg_degree_out: float = 0.5,
     seed: int = 0,
+    min_degree: int = 1,
 ):
     """Memory-light SBM for large n (>= 10k nodes, streaming benchmarks).
 
@@ -139,6 +154,13 @@ def sparse_sbm_graph(
     binomial edge COUNT per block pair and then draws endpoints, so cost
     is O(E).  Expected within-block degree is `avg_degree_in`, expected
     cross-block degree `avg_degree_out`.  Returns (EdgeList, labels).
+
+    Isolated nodes chain to their block neighbour.  ``min_degree`` > 1
+    then tops every node below it up with edges to random same-block
+    partners.  At a mean degree near 6 and a few hundred thousand
+    nodes, Poisson degrees leave small components and dangling paths
+    whose localized Laplacian eigenvalues fall below the community
+    ones; a floor of 3 removes them.
     """
     rng = np.random.default_rng(seed)
     sizes = np.full((num_blocks,), num_nodes // num_blocks, dtype=np.int64)
@@ -166,10 +188,7 @@ def sparse_sbm_graph(
                 chunks.append(np.stack([i, j], axis=1))
     edges = (np.concatenate(chunks, axis=0) if chunks
              else np.zeros((0, 2), np.int64))
-    edges = edges[edges[:, 0] != edges[:, 1]]  # drop self loops
-    lo = np.minimum(edges[:, 0], edges[:, 1])
-    hi = np.maximum(edges[:, 0], edges[:, 1])
-    edges = np.unique(np.stack([lo, hi], axis=1), axis=0)
+    edges = _canonical_edges(edges)
     # ensure no isolated nodes (chain to the next node in the same block;
     # a size-1 block chains to its global neighbour instead)
     present = np.zeros(num_nodes, bool)
@@ -184,7 +203,26 @@ def sparse_sbm_graph(
         extra.append((min(u, v), max(u, v)))
     if extra:
         edges = np.concatenate([edges, np.asarray(extra, np.int64)], axis=0)
+    while min_degree > 1:
+        deg = np.bincount(edges.ravel(), minlength=num_nodes)
+        short = np.nonzero((deg < min_degree)
+                           & (sizes[labels] > min_degree))[0]
+        if not len(short):
+            break
+        v = np.repeat(short, min_degree - deg[short])
+        blk = labels[v]
+        u = starts[blk] + rng.integers(0, sizes[blk])
+        edges = _canonical_edges(np.concatenate(
+            [edges, np.stack([v, u], axis=1)], axis=0))
     return make_edge_list(edges.astype(np.int32), num_nodes), labels
+
+
+def _canonical_edges(edges: np.ndarray) -> np.ndarray:
+    """Drop self loops, orient (lo, hi) and deduplicate."""
+    edges = edges[edges[:, 0] != edges[:, 1]]
+    lo = np.minimum(edges[:, 0], edges[:, 1])
+    hi = np.maximum(edges[:, 0], edges[:, 1])
+    return np.unique(np.stack([lo, hi], axis=1), axis=0)
 
 
 def power_law_graph(

@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+_HI = jax.lax.Precision.HIGHEST  # TPU's default is one bf16 pass
+
 
 def subspace_error(v: jax.Array, v_star: jax.Array) -> jax.Array:
     """Normalized subspace error, Eq. (15):  1 - tr(U* P_t) / k.
@@ -14,7 +16,7 @@ def subspace_error(v: jax.Array, v_star: jax.Array) -> jax.Array:
     k = v_star.shape[1]
     q, _ = jnp.linalg.qr(v)  # orthonormal basis of span(v)
     # tr(V* V*^T Q Q^T) = ||V*^T Q||_F^2
-    m = v_star.T @ q
+    m = jnp.matmul(v_star.T, q, precision=_HI)
     return 1.0 - jnp.sum(m * m) / k
 
 
@@ -41,8 +43,8 @@ def panel_residual(v: jax.Array, av: jax.Array, eps: float = 1e-30) -> jax.Array
     convergence and by warm-start to decide restart-vs-continue (columns
     of V are assumed orthonormal, as solver states maintain).
     """
-    rayleigh = v.T @ av  # (k, k)
-    r = av - v @ rayleigh
+    rayleigh = jnp.matmul(v.T, av, precision=_HI)  # (k, k)
+    r = av - jnp.matmul(v, rayleigh, precision=_HI)
     return jnp.linalg.norm(r) / jnp.maximum(jnp.linalg.norm(av), eps)
 
 

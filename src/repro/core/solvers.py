@@ -30,6 +30,11 @@ MatVec = Callable[[jax.Array], jax.Array]
 # stochastic operators additionally take a PRNG key
 StochMatVec = Callable[[jax.Array, jax.Array], jax.Array]
 
+# f32 matmuls at full precision: TPU's default is one bf16 pass, which
+# caps panel products at ~3 significant digits (the residual target is
+# 2e-3).  Other backends compute f32 exactly either way.
+_HI = jax.lax.Precision.HIGHEST
+
 
 class SolverState(NamedTuple):
     v: jax.Array  # (n, k) current estimate, orthonormal columns
@@ -73,12 +78,12 @@ def mu_eg_step(state: SolverState, av: jax.Array, lr: float) -> SolverState:
     v_i   <- normalize(v_i + lr * r_i)
     """
     v = state.v
-    vav = v.T @ av  # (k, k): [i, j] = <v_i, A v_j>
+    vav = jnp.matmul(v.T, av, precision=_HI)  # [i, j] = <v_i, A v_j>
     # strictly-lower mask: penalties from parents j < i
     k = v.shape[1]
     lower = jnp.tril(jnp.ones((k, k), v.dtype), k=-1)
     # penalty_i = sum_{j<i} vav[i, j] * v_j  -> columns: V @ (lower * vav)^T
-    penalties = v @ (lower * vav).T
+    penalties = jnp.matmul(v, (lower * vav).T, precision=_HI)
     grad = av - penalties
     grad = grad - v * jnp.sum(v * grad, axis=0, keepdims=True)  # Riemannian
     vn = v + lr * grad
@@ -109,7 +114,7 @@ def panel_gram2k(v: jax.Array, av: jax.Array) -> jax.Array:
     what lets a model-sharded tick compute it per shard on owned rows
     and psum the contributions fused with the panel assembly."""
     x = jnp.concatenate([v, av], axis=1)
-    return x.T @ x
+    return jnp.matmul(x.T, x, precision=_HI)
 
 
 def mu_eg_step_from_gram(state: SolverState, av: jax.Array,
@@ -130,7 +135,8 @@ def mu_eg_step_from_gram(state: SolverState, av: jax.Array,
 
     k = state.v.shape[1]
     m1, m2, colscale = eg_ref.coefficient_matrices(gram, k, lr)
-    vn = (state.v @ m1 + av @ m2) * colscale[None, :]
+    vn = (jnp.matmul(state.v, m1, precision=_HI)
+          + jnp.matmul(av, m2, precision=_HI)) * colscale[None, :]
     return SolverState(v=vn, step=state.step + 1)
 
 

@@ -13,9 +13,9 @@ one-hot incidence BLOCKS in VMEM and rides the MXU:
 
 one-hot variant (``edge_spmm``, n <= ONE_HOT_NODE_LIMIT = 4096):
 
-    X_blk = onehot(src) - onehot(dst)          (BE, n)   built via iota
-    D     = X_blk @ V                           (BE, k)   MXU
-    Y    += X_blk^T @ (w * D)                   (n, k)    MXU
+    X_t   = onehot(src)^T - onehot(dst)^T      (n, BE)   built via iota
+    D     = X_t^T @ V                           (BE, k)   MXU
+    Y    += (X_t * w) @ D                       (n, k)    MXU
 
 Grid over edge blocks; Y accumulates in the output ref.  V is assumed to
 fit VMEM (n x k panels with k <= 128; the backend layer caps this
@@ -29,10 +29,10 @@ node-blocked variant (``edge_spmm_nb``, any n):
     edge into two directed half-edges (u <- o, weight w), buckets them
     by the node-block of the DESTINATION u, and pre-gathers the source
     rows G = V[o].  The kernel then only ever holds a (block_n, k)
-    panel slice plus a (BE, block_n) LOCAL one-hot in VMEM:
+    panel slice plus a (block_n, BE) LOCAL one-hot in VMEM:
 
     out[b]  = deg[b] * V[b]                     (init, first chunk of b)
-    out[b] -= onehot(u_local)^T @ (w * G_chunk) (BE, block_n) MXU per chunk
+    out[b] -= (onehot(u_local)^T * w) @ G_chunk (block_n, BE) MXU per chunk
 
     The chunk layout is CSR-style VARIABLE-per-block: a hub node-block
     owns many chunks, a sparse one owns a single chunk, and the grid is
@@ -48,6 +48,11 @@ node-blocked variant (``edge_spmm_nb``, any n):
     the standard Pallas grid pipeline, i.e. the slice for chunk j+1 is
     double-buffered behind chunk j's MXU work.
 
+Per-edge streams (src/dst/w, u_local/w) and the degrees reach the
+kernels as lane-dense (1, BE) rows, which is why both build their
+one-hot blocks transposed (edge index on lanes).  Matmuls run at
+HIGHEST precision.
+
 Both kernels end with the fused AFFINE EPILOGUE
 
     out = alpha * (L V)_block + beta * V_block
@@ -60,12 +65,14 @@ recovers the plain matvec.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# f32 matmuls at full precision: the default on TPU is one bf16 pass,
+# which would cap the matvec at ~3 significant digits.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _edge_spmm_kernel(src_ref, dst_ref, w_ref, v_ref, ab_ref, out_ref):
@@ -77,18 +84,28 @@ def _edge_spmm_kernel(src_ref, dst_ref, w_ref, v_ref, ab_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     n = v_ref.shape[0]
-    be = src_ref.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (be, n), 1)
-    oh_src = (src_ref[...][:, None] == cols).astype(jnp.float32)
-    oh_dst = (dst_ref[...][:, None] == cols).astype(jnp.float32)
-    x_blk = oh_src - oh_dst  # (BE, n) incidence rows
-    d = jnp.dot(x_blk, v_ref[...], preferred_element_type=jnp.float32)
-    wd = w_ref[...][:, None] * d
-    out_ref[...] += jnp.dot(x_blk.T, wd, preferred_element_type=jnp.float32)
+    be = src_ref.shape[-1]
+    # edge streams arrive as lane-dense (1, BE) rows, so the incidence
+    # block is built transposed: x_t[c, e] = [src_e == c] - [dst_e == c]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n, be), 0)
+    x_t = ((rows == src_ref[...]).astype(jnp.float32)
+           - (rows == dst_ref[...]).astype(jnp.float32))  # (n, BE)
+    d = jax.lax.dot_general(  # X_blk @ V, contracting x_t's node axis
+        x_t, v_ref[...], (((0,), (0,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
+    out_ref[...] += jnp.dot(x_t * w_ref[...], d, precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(e == ne - 1)
     def _epilogue():
         out_ref[...] = ab_ref[0] * out_ref[...] + ab_ref[1] * v_ref[...]
+
+
+def _edge_rows(x: jax.Array, block_e: int) -> jax.Array:
+    """(E,) edge stream -> (E / block_e, 1, block_e): each grid step then
+    reads one lane-dense (1, block_e) row.  A 1-D (block_e,) block is
+    refused by Mosaic once XLA tiles the stream T(1024)."""
+    return x.reshape(x.shape[0] // block_e, 1, block_e)
 
 
 def edge_spmm(src: jax.Array, dst: jax.Array, w: jax.Array, v: jax.Array,
@@ -96,27 +113,27 @@ def edge_spmm(src: jax.Array, dst: jax.Array, w: jax.Array, v: jax.Array,
               *, block_e: int = 128, interpret: bool = False) -> jax.Array:
     """Y = alpha * sum_e w_e x_e x_e^T V + beta * V over the edge batch.
     ``ab`` is the (2,) [alpha, beta] epilogue (default [1, 0] == plain
-    matvec).  E % block_e == 0 (ops.py pads with zero-weight edges)."""
+    matvec), read as scalars from SMEM.  E % block_e == 0 (ops.py pads
+    with zero-weight edges)."""
     e = src.shape[0]
     n, k = v.shape
     assert e % block_e == 0, (e, block_e)
     if ab is None:
         ab = jnp.asarray([1.0, 0.0], jnp.float32)
-    grid = (e // block_e,)
+    edge_spec = pl.BlockSpec((None, 1, block_e), lambda i: (i, 0, 0))
     return pl.pallas_call(
         _edge_spmm_kernel,
-        grid=grid,
+        grid=(e // block_e,),
         in_specs=[
-            pl.BlockSpec((block_e,), lambda i: (i,)),
-            pl.BlockSpec((block_e,), lambda i: (i,)),
-            pl.BlockSpec((block_e,), lambda i: (i,)),
+            edge_spec, edge_spec, edge_spec,
             pl.BlockSpec((n, k), lambda i: (0, 0)),
-            pl.BlockSpec((2,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((n, k), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), jnp.float32),
         interpret=interpret,
-    )(src, dst, w, v, ab)
+    )(_edge_rows(src, block_e), _edge_rows(dst, block_e),
+      _edge_rows(w, block_e), v, ab)
 
 
 def _edge_spmm_nb_kernel(cb_ref, u_ref, w_ref, g_ref, deg_ref, v_ref,
@@ -130,18 +147,21 @@ def _edge_spmm_nb_kernel(cb_ref, u_ref, w_ref, g_ref, deg_ref, v_ref,
     prev = cb_ref[jnp.maximum(j - 1, 0)]
     is_first = jnp.logical_or(j == 0, prev != blk)
     is_last = jnp.logical_or(j == nc - 1, cb_ref[j + 1] != blk)
+    bn, kp = out_ref.shape
 
     @pl.when(is_first)
     def _init():
-        out_ref[...] = deg_ref[...][:, None] * v_ref[...]
+        # the (1, block_n) degree row becomes a (block_n, kp) column
+        # broadcast through one transpose
+        deg_col = jnp.broadcast_to(deg_ref[...], (kp, bn)).T
+        out_ref[...] = deg_col * v_ref[...]
 
-    bn = out_ref.shape[0]
-    be = u_ref.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (be, bn), 1)
-    oh = (u_ref[...][:, None] == cols).astype(jnp.float32)  # local dest
-    out_ref[...] -= jnp.dot(
-        oh.T, w_ref[...][:, None] * g_ref[...],
-        preferred_element_type=jnp.float32)
+    be = u_ref.shape[-1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bn, be), 0)
+    # weighted local one-hot, transposed: oh_t[r, e] = w_e [u_e == r]
+    oh_t = jnp.where(rows == u_ref[...], w_ref[...], 0.0)  # (block_n, BE)
+    out_ref[...] -= jnp.dot(oh_t, g_ref[...], precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(is_last)
     def _epilogue():
@@ -163,8 +183,9 @@ def edge_spmm_nb(u_local: jax.Array, w: jax.Array, gathered: jax.Array,
     pre-gathered into ``gathered`` = V[other] and streamed (BE, k) at a
     time by the grid pipeline.  VMEM per grid step: one (block_n, k)
     panel slice, one (block_e, k) gathered chunk, and the
-    (block_e, block_n) local one-hot — independent of total n and of
-    graph skew.
+    (block_n, block_e) local one-hot — independent of total n and of
+    graph skew.  The per-half-edge streams and the degrees are read as
+    lane-dense rows (see :func:`_edge_rows`); ``ab`` sits in SMEM.
     """
     np_, k = v.shape
     assert np_ % block_n == 0, (np_, block_n)
@@ -172,16 +193,16 @@ def edge_spmm_nb(u_local: jax.Array, w: jax.Array, gathered: jax.Array,
         (u_local.shape, num_chunks, block_e)
     assert chunk_block.shape[0] == num_chunks + 1, \
         (chunk_block.shape, num_chunks)
+    edge_spec = pl.BlockSpec((None, 1, block_e), lambda j, cb: (j, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(num_chunks,),
         in_specs=[
-            pl.BlockSpec((block_e,), lambda j, cb: (j,)),
-            pl.BlockSpec((block_e,), lambda j, cb: (j,)),
+            edge_spec, edge_spec,
             pl.BlockSpec((block_e, k), lambda j, cb: (j, 0)),
-            pl.BlockSpec((block_n,), lambda j, cb: (cb[j],)),
+            pl.BlockSpec((None, 1, block_n), lambda j, cb: (cb[j], 0, 0)),
             pl.BlockSpec((block_n, k), lambda j, cb: (cb[j], 0)),
-            pl.BlockSpec((2,), lambda j, cb: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_n, k), lambda j, cb: (cb[j], 0)),
     )
@@ -190,4 +211,5 @@ def edge_spmm_nb(u_local: jax.Array, w: jax.Array, gathered: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((np_, k), jnp.float32),
         interpret=interpret,
-    )(chunk_block, u_local, w, gathered, deg, v, ab)
+    )(chunk_block, _edge_rows(u_local, block_e), _edge_rows(w, block_e),
+      gathered, _edge_rows(deg, block_n), v, ab)

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_HIGHEST = jax.lax.Precision.HIGHEST  # TPU's default is one bf16 pass
+
 
 def _gram2k_kernel(v_ref, av_ref, out_ref):
     i = pl.program_id(0)
@@ -34,7 +36,8 @@ def _gram2k_kernel(v_ref, av_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     cat = jnp.concatenate([v_ref[...], av_ref[...]], axis=1)  # (bn, 2k)
-    out_ref[...] += jnp.dot(cat.T, cat, preferred_element_type=jnp.float32)
+    out_ref[...] += jnp.dot(cat.T, cat, precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
 
 
 def gram2k(v: jax.Array, av: jax.Array, *, block_n: int = 512,
@@ -56,8 +59,10 @@ def gram2k(v: jax.Array, av: jax.Array, *, block_n: int = 512,
 
 
 def _panel_mix_kernel(v_ref, av_ref, m1_ref, m2_ref, scale_ref, out_ref):
-    acc = jnp.dot(v_ref[...], m1_ref[...], preferred_element_type=jnp.float32)
-    acc += jnp.dot(av_ref[...], m2_ref[...], preferred_element_type=jnp.float32)
+    acc = jnp.dot(v_ref[...], m1_ref[...], precision=_HIGHEST,
+                  preferred_element_type=jnp.float32)
+    acc += jnp.dot(av_ref[...], m2_ref[...], precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)
     out_ref[...] = acc * scale_ref[0:1, :]
 
 

@@ -2,12 +2,14 @@
 import jax
 import jax.numpy as jnp
 
+_HI = jax.lax.Precision.HIGHEST  # TPU's default is one bf16 pass
+
 
 def mu_eg_update(v: jax.Array, av: jax.Array, lr: float) -> jax.Array:
     k = v.shape[1]
-    vav = v.T @ av
+    vav = jnp.matmul(v.T, av, precision=_HI)
     lower = jnp.tril(jnp.ones((k, k), v.dtype), k=-1)
-    penalties = v @ (lower * vav).T
+    penalties = jnp.matmul(v, (lower * vav).T, precision=_HI)
     grad = av - penalties
     grad = grad - v * jnp.sum(v * grad, axis=0, keepdims=True)
     vn = v + lr * grad
@@ -29,14 +31,14 @@ def coefficient_matrices(s2: jax.Array, k: int, lr: float):
     eye = jnp.eye(k, dtype=s2.dtype)
     lower = jnp.tril(jnp.ones((k, k), s2.dtype), k=-1)
     c0 = (lower * vav).T
-    d = jnp.diagonal(vav) - jnp.diagonal(vv @ c0)
+    d = jnp.diagonal(vav) - jnp.diagonal(jnp.matmul(vv, c0, precision=_HI))
     m1 = eye - lr * (c0 + jnp.diag(d))
     m2 = lr * eye
-    norm2 = (
-        jnp.diagonal(m1.T @ vv @ m1)
-        + jnp.diagonal(m1.T @ vav @ m2)
-        + jnp.diagonal(m2.T @ vav.T @ m1)
-        + jnp.diagonal(m2.T @ avav @ m2)
-    )
+    def quad(a, s, b):
+        return jnp.diagonal(
+            jnp.linalg.multi_dot([a.T, s, b], precision=_HI))
+
+    norm2 = (quad(m1, vv, m1) + quad(m1, vav, m2) + quad(m2, vav.T, m1)
+             + quad(m2, avav, m2))
     colscale = jax.lax.rsqrt(jnp.maximum(norm2, 1e-60))
     return m1, m2, colscale

@@ -20,6 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_HIGHEST = jax.lax.Precision.HIGHEST  # TPU's default is one bf16 pass
 
 
 def _poly_step_kernel(l_ref, u_in_ref, u_row_ref, c_ref, out_ref):
@@ -33,7 +36,8 @@ def _poly_step_kernel(l_ref, u_in_ref, u_row_ref, c_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     out_ref[...] += jnp.dot(
-        l_ref[...], u_in_ref[...], preferred_element_type=jnp.float32)
+        l_ref[...], u_in_ref[...], precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(j == nj - 1)
     def _epilogue():
@@ -58,7 +62,7 @@ def poly_step(l_mat: jax.Array, u: jax.Array, c: float | jax.Array,
             pl.BlockSpec((block_m, block_k), lambda i, j: (i, j)),  # L tile
             pl.BlockSpec((block_k, k), lambda i, j: (j, 0)),  # U (reduce)
             pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),  # U (row, AXPY)
-            pl.BlockSpec((1,), lambda i, j: (0,)),  # c scalar
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # c scalar
         ],
         out_specs=pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), jnp.float32),
@@ -74,7 +78,8 @@ def _matmul_kernel(a_ref, b_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     out_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+        a_ref[...], b_ref[...], precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def dense_matvec_panel(l_mat: jax.Array, u: jax.Array,

@@ -8,15 +8,17 @@
 - metrics.py — ``ServeMetrics``: per-request latency histograms
   (p50/p99), pipeline counters, gauges.
 - http.py    — ``ServeHTTP``: stdlib JSON-over-HTTP front end
-  (``UnknownSessionError`` -> 404, ``ValueError`` -> 400).
+  (``UnknownSessionError`` -> 404, ``ValueError`` -> 400,
+  ``EngineError`` -> 503).
 - __main__.py — ``python -m repro.serve`` process shell with clean
-  SIGTERM shutdown.
+  SIGTERM shutdown (non-zero exit after an engine failure).
 """
 from repro.serve.metrics import LatencyHistogram, ServeMetrics
 from repro.serve.results import ResultVersion, VersionedResults
-from repro.serve.server import Server, ServerConfig
+from repro.serve.server import EngineError, Server, ServerConfig
 
 __all__ = [
+    "EngineError",
     "LatencyHistogram",
     "ResultVersion",
     "ServeMetrics",

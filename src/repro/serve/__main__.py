@@ -4,7 +4,9 @@ Prints one parseable banner line — ``SERVING host=<h> port=<p>`` — once
 the socket is bound (port 0 picks a free port, so harnesses read the
 banner rather than guessing), then serves until SIGTERM/SIGINT, which
 trigger a clean shutdown: the acceptor stops, the engine thread drains
-every staged batch, and the process exits 0.  ``scripts/ci.sh`` and the
+every staged batch, and the process exits 0.  If the engine thread
+dies, the process shuts down on its own, prints ``ENGINE FAILED`` with
+the cause on stderr and exits 1.  ``scripts/ci.sh`` and the
 bench's ``--http-smoke`` lane drive exactly this contract.
 """
 from __future__ import annotations
@@ -35,9 +37,12 @@ def main(argv=None) -> int:
 
     # deferred: the banner contract says nothing prints before imports
     # succeed, and jax import cost should not be paid for --help
+    from repro.compile_cache import enable_compile_cache
     from repro.serve.http import ServeHTTP
-    from repro.serve.server import Server, ServerConfig
+    from repro.serve.server import EngineError, Server, ServerConfig
     from repro.stream.service import ServiceConfig
+
+    enable_compile_cache()
 
     cfg = ServerConfig(
         service=ServiceConfig(
@@ -45,7 +50,8 @@ def main(argv=None) -> int:
             steps_per_tick=args.steps_per_tick, tol=args.tol,
             seed=args.seed),
         pipeline=args.pipeline)
-    front = ServeHTTP(Server(cfg), host=args.host, port=args.port)
+    server = Server(cfg)
+    front = ServeHTTP(server, host=args.host, port=args.port)
     front.start()
     print(f"SERVING host={front.host} port={front.port}", flush=True)
 
@@ -56,8 +62,14 @@ def main(argv=None) -> int:
 
     signal.signal(signal.SIGTERM, _stop)
     signal.signal(signal.SIGINT, _stop)
-    done.wait()
-    front.stop()
+    while not done.wait(timeout=0.5):
+        if server.engine_error is not None:
+            break
+    try:
+        front.stop()
+    except EngineError as e:
+        print(f"ENGINE FAILED: {e}", file=sys.stderr, flush=True)
+        return 1
     print("STOPPED", flush=True)
     return 0
 

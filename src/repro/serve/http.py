@@ -16,7 +16,9 @@ DELETE /v1/sessions/{sid}                  evict
 Error mapping is the typed-error satellite made visible on the wire:
 :class:`~repro.stream.service.UnknownSessionError` -> **404**,
 ``ValueError`` (malformed batch / bad mode / duplicate admit) -> **400**,
-anything else -> **500** with the exception text in the JSON body.
+:class:`~repro.serve.server.EngineError` (the engine thread died) ->
+**503**, anything else -> **500** with the exception text in the JSON
+body.  ``/healthz`` answers 503 once the engine has died.
 
 Request threads are the ThreadingHTTPServer pool; they only ever stage
 pushes and read the versioned results store, so the engine thread keeps
@@ -30,7 +32,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import numpy as np
 
-from repro.serve.server import Server
+from repro.serve.server import EngineError, Server
 from repro.stream.service import UnknownSessionError
 
 
@@ -86,6 +88,9 @@ class _Handler(BaseHTTPRequestHandler):
         except UnknownSessionError as e:
             self._reply(404, {"error": str(e)})
             return
+        except EngineError as e:
+            self._reply(503, {"error": str(e)})
+            return
         except (ValueError, json.JSONDecodeError) as e:
             self._reply(400, {"error": str(e)})
             return
@@ -99,7 +104,10 @@ class _Handler(BaseHTTPRequestHandler):
         srv = self.server_obj
         path = self.path.split("?", 1)[0].rstrip("/")
         if method == "GET" and path == "/healthz":
-            self._reply(200, {"ok": True, "running": srv.running})
+            err = srv.engine_error
+            self._reply(200 if err is None else 503,
+                        {"ok": err is None, "running": srv.running,
+                         "error": None if err is None else repr(err)})
             return True
         if method == "GET" and path == "/metrics":
             self._reply(200, srv.stats())

@@ -34,6 +34,12 @@ pushes run on request threads); any number of request threads stage
 pushes and read results concurrently.  Unknown or evicted session ids
 raise :class:`~repro.stream.service.UnknownSessionError` end to end —
 the HTTP layer maps it to 404.
+
+Engine failure is terminal and loud: an exception escaping the engine
+thread's loop (a kernel the device's compiler refuses inside a tick,
+say) is recorded, the thread ends, and from then on every request,
+``flush``, ``wait_converged`` and ``stop`` raise :class:`EngineError`
+chained to it — a dead engine never serves stale versions as healthy.
 """
 from __future__ import annotations
 
@@ -55,6 +61,10 @@ from repro.stream.service import (
 
 REQUEST_OPS = ("admit", "push", "labels", "summary", "evict")
 PIPELINES = ("double_buffer", "serialized")
+
+
+class EngineError(RuntimeError):
+    """The engine thread died; its exception is the ``__cause__``."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,6 +140,18 @@ class Server:
         self._t0 = time.perf_counter()
         self._stop_flag = False
         self._thread: threading.Thread | None = None
+        self._engine_error: Exception | None = None
+
+    @property
+    def engine_error(self) -> Exception | None:
+        """The exception that killed the engine thread, if any."""
+        return self._engine_error
+
+    def _check_engine(self) -> None:
+        err = self._engine_error
+        if err is not None:
+            raise EngineError(
+                f"engine thread died: {type(err).__name__}: {err}") from err
 
     # ------------------------------------------------------------------
     # request API
@@ -141,6 +163,7 @@ class Server:
               resume_panel=None) -> dict:
         """Admit a graph; commits result version 1 immediately, so
         labels/summary are queryable before the first tick lands."""
+        self._check_engine()
         with self.metrics.timed("admit"):
             edges = np.asarray(edges, np.int64).reshape(-1, 2)
             g = lap.make_edge_list(edges, int(num_nodes), weights=weights)
@@ -168,6 +191,7 @@ class Server:
 
     def push(self, sid: str, edges, weights, mode: str = "set") -> dict:
         """Stage (or, serialized pipeline, apply) one edge batch."""
+        self._check_engine()
         with self.metrics.timed("push"):
             if mode not in ("set", "add"):
                 raise ValueError(f"unknown update mode {mode!r}")
@@ -203,6 +227,7 @@ class Server:
 
         Served entirely from the versioned results store: no engine
         lock, and repeated queries at one version are cached."""
+        self._check_engine()
         with self.metrics.timed("labels"):
             with self._stage_lock:
                 labeler = self._labelers.get(sid)
@@ -218,6 +243,7 @@ class Server:
             return self.summary_unmetered(sid)
 
     def summary_unmetered(self, sid: str) -> dict:
+        self._check_engine()
         out = self.results.summary(sid)
         out["sid"] = sid
         return out
@@ -226,6 +252,7 @@ class Server:
         """Remove a session; staged-but-undrained batches are dropped
         (counted in ``dropped_batches``).  The returned summary carries
         the live panel for ``admit(resume_panel=...)`` re-admission."""
+        self._check_engine()
         with self.metrics.timed("evict"):
             with self._stage_lock:
                 self._known.discard(sid)
@@ -247,6 +274,9 @@ class Server:
         uptime = max(time.perf_counter() - self._t0, 1e-9)
         snap["gauges"]["tick_utilization"] = self._tick_busy_s / uptime
         snap["results"] = self.results.stats()
+        err = self._engine_error
+        snap["engine_error"] = (None if err is None
+                                else f"{type(err).__name__}: {err}")
         with self._engine_lock:
             svc = self.service
             snap["engine"] = {
@@ -337,11 +367,16 @@ class Server:
         return version
 
     def _serve_loop(self) -> None:
-        while not self._stop_flag:
-            if not self.step():
-                self._wake.wait(timeout=self.cfg.idle_sleep_s)
-                self._wake.clear()
-        self.step()  # final drain: stop() loses no staged update
+        try:
+            while not self._stop_flag:
+                if not self.step():
+                    self._wake.wait(timeout=self.cfg.idle_sleep_s)
+                    self._wake.clear()
+            self.step()  # final drain: stop() loses no staged update
+        except Exception as e:  # the engine boundary: record, then die
+            self._engine_error = e
+            with self._drain_cond:  # wake flush() waiters to see it
+                self._drain_cond.notify_all()
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -362,15 +397,16 @@ class Server:
 
     def stop(self, timeout: float = 30.0) -> None:
         """Stop the engine thread after a final drain (clean shutdown:
-        every staged batch is applied or counted dropped)."""
-        if self._thread is None:
-            return
-        self._stop_flag = True
-        self._wake.set()
-        self._thread.join(timeout=timeout)
-        if self._thread.is_alive():
-            raise RuntimeError("engine thread did not stop in time")
-        self._thread = None
+        every staged batch is applied or counted dropped).  Raises
+        :class:`EngineError` when the engine thread had died."""
+        if self._thread is not None:
+            self._stop_flag = True
+            self._wake.set()
+            self._thread.join(timeout=timeout)
+            if self._thread.is_alive():
+                raise RuntimeError("engine thread did not stop in time")
+            self._thread = None
+        self._check_engine()
 
     def __enter__(self) -> "Server":
         return self.start()
@@ -380,12 +416,15 @@ class Server:
 
     def flush(self, timeout: float = 60.0) -> bool:
         """Block until every batch staged before the call has been
-        drained (applied or dropped).  Returns False on timeout."""
+        drained (applied or dropped).  Returns False on timeout; raises
+        :class:`EngineError` once the engine thread has died."""
+        self._check_engine()
         if not self.running:
             self.step()
             return True
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
+            self._check_engine()
             with self._stage_lock:
                 pending = bool(self._front)
             with self._drain_cond:
@@ -393,8 +432,10 @@ class Server:
             self._wake.set()
             with self._drain_cond:
                 ok = self._drain_cond.wait_for(
-                    lambda: self._drained_seq > seq,
+                    lambda: (self._drained_seq > seq
+                             or self._engine_error is not None),
                     timeout=max(deadline - time.monotonic(), 0.0))
+            self._check_engine()
             if not pending and ok:
                 # an empty front buffer followed by one full step
                 # boundary: any in-flight drain has landed
@@ -411,6 +452,7 @@ class Server:
             if not self.flush(timeout=max(deadline - time.monotonic(),
                                           0.0)):
                 return False
+            self._check_engine()
             with self._engine_lock:
                 done = self.service.all_converged
             with self._stage_lock:
@@ -424,4 +466,5 @@ class Server:
         return False
 
 
-__all__ = ["PIPELINES", "REQUEST_OPS", "Server", "ServerConfig"]
+__all__ = ["EngineError", "PIPELINES", "REQUEST_OPS", "Server",
+           "ServerConfig"]

@@ -64,6 +64,7 @@ MatVec = Callable[[jax.Array], jax.Array]
 # instead (the Krylov space is numerically invariant at that point).
 _BREAKDOWN_REL = 1e-4
 _TINY = 1e-30
+_HI = jax.lax.Precision.HIGHEST  # TPU's default is one bf16 pass
 
 
 class ProbeResult(NamedTuple):
@@ -109,8 +110,9 @@ def lanczos(matvec: MatVec, v0: jax.Array, num_steps: int
         # Full reorthogonalization against every stored vector (rows > i
         # are zero, so no masking needed); twice kills the O(eps kappa)
         # residue of the first pass.
-        w = w - q.T @ (q @ w)
-        w = w - q.T @ (q @ w)
+        for _ in range(2):
+            w = w - jnp.matmul(q.T, jnp.matmul(q, w, precision=_HI),
+                               precision=_HI)
         b = jnp.linalg.norm(w)
         alive = b > _BREAKDOWN_REL * (raw_norm + _TINY)
         keep = jnp.where(alive, 1.0, 0.0)

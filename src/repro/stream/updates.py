@@ -29,6 +29,8 @@ import jax.numpy as jnp
 
 from repro.core.laplacian import edge_matvec_arrays
 
+_HI = jax.lax.Precision.HIGHEST  # TPU's default is one bf16 pass
+
 MatVec = Callable[[jax.Array], jax.Array]
 
 
@@ -49,7 +51,7 @@ class UpdateConfig:
 
 def estimate_from_panel(matvec: MatVec, v: jax.Array) -> EigenEstimate:
     """Anchor an estimate at a freshly solved panel: λ = diag(VᵀLV)."""
-    lam = jnp.diagonal(v.T @ matvec(v))
+    lam = jnp.diagonal(jnp.matmul(v.T, matvec(v), precision=_HI))
     return EigenEstimate(lam=lam, v=v, drift=jnp.zeros((), v.dtype))
 
 
@@ -99,14 +101,15 @@ def first_order_update(
     skipped (their 1/gap amplification is noise-dominated).
     """
     dv = delta_matvec(src, dst, dw, est.v)  # ΔL V, (n, k)
-    c = est.v.T @ dv  # (k, k): c[j, i] = v_jᵀ ΔL v_i
+    c = jnp.matmul(est.v.T, dv, precision=_HI)  # c[j, i] = v_jᵀ ΔL v_i
     lam_new = est.lam + jnp.diagonal(c)
     k = est.lam.shape[0]
     denom = est.lam[None, :] - est.lam[:, None]  # [j, i] = λ_i - λ_j
     offdiag = ~jnp.eye(k, dtype=bool)
     safe = offdiag & (jnp.abs(denom) > gap_floor)
     coef = jnp.where(safe, c / jnp.where(safe, denom, 1.0), 0.0)
-    v_new = est.v + est.v @ coef  # column i += Σ_j coef[j, i] v_j
+    # column i += Σ_j coef[j, i] v_j
+    v_new = est.v + jnp.matmul(est.v, coef, precision=_HI)
     q, r = jnp.linalg.qr(v_new)  # restore orthonormality
     sign = jnp.sign(jnp.diagonal(r))
     sign = jnp.where(sign == 0, 1.0, sign)

@@ -3,6 +3,7 @@ double-buffered async ingest/tick pipeline (including the threaded
 concurrency suite), and the stdlib HTTP front end."""
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -12,7 +13,7 @@ import pytest
 
 from repro.core import graphs
 from repro.core.kmeans import cluster_agreement
-from repro.serve import Server, ServerConfig, VersionedResults
+from repro.serve import EngineError, Server, ServerConfig, VersionedResults
 from repro.serve.http import ServeHTTP
 from repro.serve.metrics import LatencyHistogram, ServeMetrics
 from repro.stream.service import ServiceConfig, UnknownSessionError
@@ -354,3 +355,42 @@ def test_http_roundtrip_and_error_mapping():
         code, out = _req(base + "/v1/sessions/h1", "DELETE")
         assert code == 200 and "panel" not in out  # stripped on the wire
         assert _req(base + "/v1/sessions/h1")[0] == 404
+
+
+def test_engine_failure_surfaces_and_never_serves_as_healthy(monkeypatch):
+    """An exception inside a tick kills the engine thread: from then on
+    health, requests, flush and stop report it instead of serving stale
+    versions as if nothing happened."""
+    edges, w, n, _ = _sbm_edges(15)
+    srv = Server(ServerConfig(service=SERVE_SVC))
+    srv.admit("e1", edges, n, weights=w, num_clusters=3,
+              edge_capacity=1024)  # unconverged: the first tick fires
+
+    def refused_tick():
+        raise RuntimeError("kernel refused by the compiler")
+
+    monkeypatch.setattr(srv.service, "tick", refused_tick)
+    front = ServeHTTP(srv).start()
+    base = f"http://{front.host}:{front.port}"
+    deadline = time.monotonic() + 60.0
+    while srv.running and time.monotonic() < deadline:
+        time.sleep(0.01)
+    try:
+        assert not srv.running
+        assert isinstance(srv.engine_error, RuntimeError)
+        code, out = _req(base + "/healthz")
+        assert code == 503 and not out["ok"] and "refused" in out["error"]
+        assert _req(base + "/v1/sessions/e1/labels")[0] == 503
+        assert srv.stats()["engine_error"].startswith("RuntimeError")
+        with pytest.raises(EngineError, match="kernel refused"):
+            srv.labels("e1")
+        with pytest.raises(EngineError):
+            srv.summary("e1")
+        with pytest.raises(EngineError):
+            srv.push("e1", [[0, 1]], [1.0])
+        with pytest.raises(EngineError):
+            srv.flush(timeout=5.0)
+    finally:
+        with pytest.raises(EngineError) as info:
+            front.stop()
+    assert isinstance(info.value.__cause__, RuntimeError)
