@@ -57,12 +57,6 @@ CONVERGE_DEADLINE_S = 780.0
 SMALL_AGREEMENT, BIG_AGREEMENT, TICK_MAXERR = 0.99, 0.95, 1e-4
 SHARDED_CHIPS = 4
 
-_COMPILE_EVENTS = (
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
-)
-
 
 class SmokeFailure(Exception):
     pass
@@ -75,32 +69,29 @@ def check(ok: bool, msg: str) -> None:
 
 class PhaseClock:
     """Per-phase wall seconds, with compile seconds (trace + lower +
-    backend compile, from JAX's monitoring events) reported apart."""
+    backend compile) and persistent-cache loads reported apart: each
+    phase is a ``repro.tracing`` span, and its compiles are those of the
+    spans that ran inside it, on any thread."""
 
     def __init__(self):
-        import jax
-
         self.compile_s = 0.0
         self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, secs, **_):
-        if event in _COMPILE_EVENTS:
-            self.compile_s += secs
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        t0, c0, h0 = time.perf_counter(), self.compile_s, self.cache_hits
-        yield
-        print(f"phase {name}: wall_s={time.perf_counter() - t0:.2f} "
-              f"compile_s={self.compile_s - c0:.2f} "
-              f"cache_hits={self.cache_hits - h0}", flush=True)
+        from repro import tracing
+
+        with tracing.span(f"smoke.{name}") as sp:
+            yield
+        me = sp.record
+        inside = [r for r in tracing.records()
+                  if me.start_ns <= r.start_ns and r.end_ns <= me.end_ns]
+        compile_s = sum(r.compile_s for r in inside)
+        hits = sum(r.cache_loads for r in inside)
+        self.compile_s += compile_s
+        self.cache_hits += hits
+        print(f"phase {name}: wall_s={me.seconds:.2f} "
+              f"compile_s={compile_s:.2f} cache_hits={hits}", flush=True)
 
 
 def agreement(labels, truth, k: int) -> float:

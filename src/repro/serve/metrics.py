@@ -1,7 +1,7 @@
 """Serving observability: latency histograms, counters, and gauges.
 
-Pure-host, dependency-free instrumentation for the request layer.  The
-design constraints come from the ingest pipeline:
+Pure-host instrumentation for the request layer.  The design
+constraints come from the ingest pipeline:
 
 * recording must be CHEAP and lock-short — every request on the hot
   path records exactly one histogram sample and a couple of counter
@@ -15,15 +15,21 @@ design constraints come from the ingest pipeline:
   one bucket factor, i.e. SLO-conservative.
 
 ``ServeMetrics`` is the aggregate the server owns: one histogram per
-request type (admit / push / labels / summary / evict), counters for
-the pipeline (staged / applied / dropped batches, commits, ticks), and
-gauges (queue depth, tick utilization).  ``snapshot()`` returns a plain
-JSON-able dict — the payload of the HTTP front end's ``/metrics``.
+request type (admit / push / labels / summary / evict) plus the
+server's ``push_wait`` (staging to drain), counters for the pipeline
+(staged / applied / dropped batches, commits, ticks), and gauges (queue
+depth, tick utilization).  ``timed`` times a request as one
+:mod:`repro.tracing` span, ``request.<op>``, whose duration fills the
+histogram.  ``snapshot()`` returns a plain JSON-able dict — the payload
+of the HTTP front end's ``/metrics``.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
+
+from repro import tracing
 
 LATENCY_BUCKET_FACTOR = 1.6
 _BASE_S = 1e-6  # first bucket upper edge: 1 microsecond
@@ -115,9 +121,17 @@ class ServeMetrics:
                 h = self._hists[op] = LatencyHistogram()
             h.record(seconds)
 
+    @contextlib.contextmanager
     def timed(self, op: str):
-        """Context manager: ``with metrics.timed("labels"): ...``."""
-        return _Timer(self, op)
+        """Context manager: ``with metrics.timed("labels"): ...`` runs
+        the block in the span ``request.<op>`` and records its duration
+        (also when the block raises)."""
+        sp = tracing.span(f"request.{op}")
+        try:
+            with sp:
+                yield
+        finally:
+            self.record(op, sp.record.seconds)
 
     def inc(self, counter: str, by: int = 1) -> None:
         with self._lock:
@@ -146,22 +160,6 @@ class ServeMetrics:
                 "counters": dict(sorted(self._counters.items())),
                 "gauges": dict(sorted(self._gauges.items())),
             }
-
-
-class _Timer:
-    __slots__ = ("_metrics", "_op", "_t0")
-
-    def __init__(self, metrics: ServeMetrics, op: str):
-        self._metrics = metrics
-        self._op = op
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self._metrics.record(self._op, time.perf_counter() - self._t0)
-        return False
 
 
 __all__ = [

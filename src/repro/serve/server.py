@@ -24,9 +24,11 @@ concerns the library never had:
   per-session tracker), and NO engine lock on the query path, so a
   slow device tick never stalls a read.
 * **observability**: per-request-type latency histograms (p50/p99 via
-  :mod:`repro.serve.metrics`), pipeline counters (staged / applied /
-  dropped batches, commits, ticks), and queue-depth / tick-utilization
-  gauges, all surfaced by :meth:`Server.stats`.
+  :mod:`repro.serve.metrics`) and the ``push_wait`` queue-wait
+  histogram, pipeline counters (staged / applied / dropped batches,
+  commits, ticks), queue-depth / tick-utilization gauges, and the
+  per-name totals of the :mod:`repro.tracing` spans the engine and the
+  service record, all surfaced by :meth:`Server.stats`.
 
 Thread model: ONE engine thread owns every ``StreamingService`` call
 (the engine lock exists only because ``admit``/``evict``/serialized
@@ -49,6 +51,7 @@ import time
 
 import numpy as np
 
+from repro import tracing
 from repro.core import laplacian as lap
 from repro.serve.metrics import ServeMetrics
 from repro.serve.results import VersionedResults
@@ -89,11 +92,12 @@ class _PendingBuffer:
     per mode, so a burst of pushes costs one ``apply_edge_batch`` each.
     """
 
-    __slots__ = ("slots", "batches_staged")
+    __slots__ = ("slots", "batches_staged", "t_first")
 
     def __init__(self):
         self.slots: dict[tuple[int, int], list] = {}
         self.batches_staged = 0
+        self.t_first = time.perf_counter()  # queue wait starts here
 
     def merge(self, edges: np.ndarray, weights: np.ndarray,
               mode: str) -> int:
@@ -127,7 +131,7 @@ class Server:
         self.cfg = cfg
         self.service = StreamingService(cfg.service)
         self.results = VersionedResults()
-        self.metrics = ServeMetrics(REQUEST_OPS)
+        self.metrics = ServeMetrics(REQUEST_OPS + ("push_wait",))
         self._engine_lock = threading.RLock()
         self._stage_lock = threading.Lock()
         self._front: dict[str, _PendingBuffer] = {}
@@ -136,8 +140,9 @@ class Server:
         self._wake = threading.Event()
         self._drain_cond = threading.Condition()
         self._drained_seq = 0
-        self._tick_busy_s = 0.0
-        self._t0 = time.perf_counter()
+        # (clock, engine.tick seconds) at start() and at stop()
+        self._ticks_from: tuple[float, float] | None = None
+        self._ticks_to: tuple[float, float] | None = None
         self._stop_flag = False
         self._thread: threading.Thread | None = None
         self._engine_error: Exception | None = None
@@ -271,8 +276,8 @@ class Server:
         """Observability snapshot: latency histograms, pipeline
         counters/gauges, engine and results-store state."""
         snap = self.metrics.snapshot()
-        uptime = max(time.perf_counter() - self._t0, 1e-9)
-        snap["gauges"]["tick_utilization"] = self._tick_busy_s / uptime
+        snap["gauges"]["tick_utilization"] = self._tick_utilization()
+        snap["spans"] = tracing.totals()
         snap["results"] = self.results.stats()
         err = self._engine_error
         snap["engine_error"] = (None if err is None
@@ -298,12 +303,45 @@ class Server:
         drained batches, run one scheduled tick, commit touched
         versions.  Returns True when any work happened.  The background
         thread calls this in a loop; tests may drive it manually on an
-        un-started server."""
+        un-started server.  An idle iteration (nothing staged, no tick
+        due) records no span."""
         with self._stage_lock:
             staged, self._front = self._front, {}
-        touched = []
-        drained = 0
+        drained, ticked = 0, {}
         with self._engine_lock:
+            if staged or self._tick_due():
+                with tracing.span("engine.step"):
+                    touched, drained = self._drain(staged)
+                    if self._tick_due():
+                        with tracing.span("engine.tick"):
+                            ticked = self.service.tick()
+                        self.metrics.inc("ticks")
+                    for sid in {*touched, *ticked}:
+                        try:
+                            self._commit(sid)
+                        except UnknownSessionError:
+                            pass  # raced an eviction; tombstone served
+        if staged:
+            with self._stage_lock:
+                depth = sum(len(b.slots) for b in self._front.values())
+            self.metrics.set_gauge("queue_depth", depth)
+        with self._drain_cond:
+            self._drained_seq += 1
+            self._drain_cond.notify_all()
+        return bool(drained or ticked)
+
+    def _tick_due(self) -> bool:
+        svc = self.service
+        return bool(svc.session_ids()) and not svc.all_converged
+
+    def _drain(self, staged: dict) -> tuple[list[str], int]:
+        """Apply the swapped-out buffers — caller holds the engine lock.
+        Returns (touched sids, staged batches applied)."""
+        touched: list[str] = []
+        drained = 0
+        if not staged:
+            return touched, drained
+        with tracing.span("engine.drain"):
             # Per-capacity-class drain batching: staged sessions group
             # by their (node, edge) capacity class, and each class pins
             # ONE batch pad (pow2 of its largest coalesced batch) for
@@ -327,6 +365,8 @@ class Server:
                 pad = max((len(edges) for _, _, batches in members
                            for edges, _, _ in batches), default=0)
                 for sid, buf, batches in members:
+                    self.metrics.record("push_wait",
+                                        time.perf_counter() - buf.t_first)
                     try:
                         for edges, ws, mode in batches:
                             self.service.apply_updates(
@@ -338,26 +378,9 @@ class Server:
                     except UnknownSessionError:
                         self.metrics.inc("dropped_batches",
                                          buf.batches_staged)
-            ticked = {}
-            if self.service.session_ids() and not self.service.all_converged:
-                t0 = time.perf_counter()
-                ticked = self.service.tick()
-                self._tick_busy_s += time.perf_counter() - t0
-                self.metrics.inc("ticks")
-            for sid in {*touched, *ticked}:
-                try:
-                    self._commit(sid)
-                except UnknownSessionError:
-                    pass  # raced an eviction; tombstone already served
-        if staged:
-            with self._stage_lock:
-                depth = sum(len(b.slots) for b in self._front.values())
-            self.metrics.set_gauge("queue_depth", depth)
-        with self._drain_cond:
-            self._drained_seq += 1
-            self._drain_cond.notify_all()
-        return bool(drained or ticked)
+        return touched, drained
 
+    @tracing.traced("engine.commit")
     def _commit(self, sid: str) -> int:
         """Version commit point — caller holds the engine lock."""
         summary = self.service.session_info(sid)
@@ -365,6 +388,22 @@ class Server:
                                       self.service.panel(sid))
         self.metrics.inc("commits")
         return version
+
+    def _tick_utilization(self) -> float:
+        """Share of the time since start() (up to stop(), once stopped)
+        spent in ``engine.tick`` spans; 0.0 before start().  The span
+        totals are process-wide, so servers ticking at once in one
+        process share them."""
+        if self._ticks_from is None:
+            return 0.0
+        t0, busy0 = self._ticks_from
+        t1, busy1 = self._ticks_to or self._tick_clock()
+        return (busy1 - busy0) / max(t1 - t0, 1e-9)
+
+    @staticmethod
+    def _tick_clock() -> tuple[float, float]:
+        busy = tracing.totals().get("engine.tick", {}).get("total_s", 0.0)
+        return time.perf_counter(), busy
 
     def _serve_loop(self) -> None:
         try:
@@ -390,6 +429,7 @@ class Server:
         if self.running:
             raise RuntimeError("server already started")
         self._stop_flag = False
+        self._ticks_from, self._ticks_to = self._tick_clock(), None
         self._thread = threading.Thread(
             target=self._serve_loop, name="serve-engine", daemon=True)
         self._thread.start()
@@ -406,6 +446,7 @@ class Server:
             if self._thread.is_alive():
                 raise RuntimeError("engine thread did not stop in time")
             self._thread = None
+            self._ticks_to = self._tick_clock()
         self._check_engine()
 
     def __enter__(self) -> "Server":

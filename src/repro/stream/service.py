@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import backend as backend_mod
 from repro.core import kmeans as km
 from repro.core import operators, program, solvers
@@ -366,6 +367,7 @@ class StreamingService:
     # spectral probing + dilation planning
     # ------------------------------------------------------------------
 
+    @tracing.traced("service.probe")
     def _rho_estimate(self, store: gs.GraphStore, n: int) -> tuple:
         """(refreshed store, rho, rho_ub, lam_k, lam_k1) — plan anchors.
 
@@ -495,6 +497,7 @@ class StreamingService:
     # admission / eviction
     # ------------------------------------------------------------------
 
+    @tracing.traced("service.admit")
     def add_graph(self, sid: str, g, num_clusters: int | None = None,
                   edge_capacity: int | None = None,
                   resume_panel=None) -> None:
@@ -519,8 +522,9 @@ class StreamingService:
         node_cap = node_capacity_class(g.num_nodes)
         cap = (gs.capacity_class(g.num_edges) if edge_capacity is None
                else edge_capacity)
-        store = gs.from_edge_list(g, capacity=self._balanced(cap),
-                                  num_nodes=node_cap)
+        with tracing.span("service.store"):
+            store = gs.from_edge_list(g, capacity=self._balanced(cap),
+                                      num_nodes=node_cap)
         store, rho, rho_ub, lam_k, lam_k1 = self._rho_estimate(
             store, g.num_nodes)
         key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed),
@@ -580,6 +584,7 @@ class StreamingService:
     # streaming updates
     # ------------------------------------------------------------------
 
+    @tracing.traced("service.apply")
     def apply_updates(self, sid: str, edges, weights,
                       mode: str = "set",
                       pad_to: int | None = None) -> gs.BatchStats:
@@ -750,19 +755,19 @@ class StreamingService:
         sharding builds the destination-aligned model layout (used by
         BOTH backends — the model tick's segment path scatters over the
         same per-shard arrays the kernel consumes)."""
+        block_n = self.cfg.tick_block_n
         if self._model_axes is not None:
-            if sess.model_blocking is None:
-                sess.model_blocking = gs.model_sharded_blocking(
-                    sess.store, self._model_shards,
-                    block_n=self.cfg.tick_block_n)
+            attr, build = "model_blocking", lambda: gs.model_sharded_blocking(
+                sess.store, self._model_shards, block_n=block_n)
         elif self._mesh is not None:
-            if sess.sharded_blocking is None:
-                sess.sharded_blocking = gs.sharded_node_blocking(
-                    sess.store, self._num_shards,
-                    block_n=self.cfg.tick_block_n)
-        elif sess.blocking is None:
-            sess.blocking = gs.node_blocking(
-                sess.store, block_n=self.cfg.tick_block_n)
+            attr, build = "sharded_blocking", lambda: gs.sharded_node_blocking(
+                sess.store, self._num_shards, block_n=block_n)
+        else:
+            attr, build = "blocking", lambda: gs.node_blocking(
+                sess.store, block_n=block_n)
+        if getattr(sess, attr) is None:
+            with tracing.span("service.layout"):
+                setattr(sess, attr, build())
 
     def _group_key(self, sess: _Session, degrees: dict | None = None
                    ) -> tuple:
@@ -879,6 +884,46 @@ class StreamingService:
             mults[i] = mult
         return mults
 
+    def _tick_operands(self, gkey: tuple, members: list[_Session],
+                       mults: np.ndarray) -> tuple:
+        """(compiled step, occupancy, operands) of one group invocation:
+        the members' edge layouts and panels stacked to the pow2
+        occupancy of the ACTIVE member count (padded with replicas of
+        the first session: converged sessions no longer ride along as
+        padding, at the cost of at most log2(max occupancy) compiled
+        buckets per group), their dilation scales, learning rates and
+        chunk budgets."""
+        occ = _next_pow2(len(members))
+        step = self._get_step(gkey, occ)
+        idx = list(range(len(members))) + [0] * (occ - len(members))
+        stack = lambda f: jnp.stack([f(members[i]) for i in idx])
+        cs = jnp.asarray(
+            [program.dilation_scale(members[i].plan, gkey[1])
+             for i in idx], jnp.float32)
+        lrs = jnp.asarray([members[i].lr for i in idx], jnp.float32)
+        # traced per-session chunk budgets: no recompile for any mix
+        chunks = jnp.asarray(mults[np.asarray(idx)], jnp.int32)
+        if self._model_axes is not None:
+            layout = lambda s: s.model_blocking
+        elif self._backend == "pallas" and self._mesh is not None:
+            layout = lambda s: s.sharded_blocking
+        elif self._backend == "pallas":
+            layout = lambda s: s.blocking
+        else:
+            layout = None
+        if layout is None:
+            # single-device segment AND sharded segment take the same
+            # stacked-edge-buffer signature (the sharded builder shards
+            # the capacity axis over the mesh)
+            edges = [stack(lambda s: s.store.src),
+                     stack(lambda s: s.store.dst),
+                     stack(lambda s: s.store.weight)]
+        else:
+            edges = [stack(lambda s, f=f: getattr(layout(s), f))
+                     for f in ("u_local", "other", "weight", "chunk_block",
+                               "deg")]
+        return step, occ, (*edges, stack(lambda s: s.v), cs, lrs, chunks)
+
     def tick(self) -> dict[str, float]:
         """Advance every unconverged session one scheduled tick — one
         compiled program invocation per (capacity class, degree) group
@@ -894,57 +939,17 @@ class StreamingService:
                 groups[self._group_key(sess, degrees)].append(sess)
         out: dict[str, float] = {}
         for gkey, g_members in groups.items():
-            deg = gkey[1]
             g_mults = self._tick_multipliers(g_members)
             for members, mults in _split_by_multiplier(g_members, g_mults):
-                # occupancy bucket follows the ACTIVE member count (pow2
-                # padded with replicas of the first session): converged
-                # sessions no longer ride along as padding, at the cost of
-                # at most log2(max occupancy) compiled buckets per group
-                occ = _next_pow2(len(members))
                 max_mult = int(mults.max())
-                step = self._get_step(gkey, occ)
-                idx = list(range(len(members))) + [0] * (occ - len(members))
-                stack = lambda f: jnp.stack([f(members[i]) for i in idx])
-                cs = jnp.asarray(
-                    [program.dilation_scale(members[i].plan, deg)
-                     for i in idx], jnp.float32)
-                lrs = jnp.asarray([members[i].lr for i in idx], jnp.float32)
-                # traced per-session chunk budgets: no recompile for any mix
-                chunks = jnp.asarray(mults[np.asarray(idx)], jnp.int32)
-                if self._model_axes is not None:
-                    vs, res = step(
-                        stack(lambda s: s.model_blocking.u_local),
-                        stack(lambda s: s.model_blocking.other),
-                        stack(lambda s: s.model_blocking.weight),
-                        stack(lambda s: s.model_blocking.chunk_block),
-                        stack(lambda s: s.model_blocking.deg),
-                        stack(lambda s: s.v), cs, lrs, chunks)
-                elif self._backend == "pallas" and self._mesh is not None:
-                    vs, res = step(
-                        stack(lambda s: s.sharded_blocking.u_local),
-                        stack(lambda s: s.sharded_blocking.other),
-                        stack(lambda s: s.sharded_blocking.weight),
-                        stack(lambda s: s.sharded_blocking.chunk_block),
-                        stack(lambda s: s.sharded_blocking.deg),
-                        stack(lambda s: s.v), cs, lrs, chunks)
-                elif self._backend == "pallas":
-                    vs, res = step(
-                        stack(lambda s: s.blocking.u_local),
-                        stack(lambda s: s.blocking.other),
-                        stack(lambda s: s.blocking.weight),
-                        stack(lambda s: s.blocking.chunk_block),
-                        stack(lambda s: s.blocking.deg),
-                        stack(lambda s: s.v), cs, lrs, chunks)
-                else:
-                    # single-device segment AND sharded segment take the
-                    # same stacked-edge-buffer signature (the sharded
-                    # builder shards the capacity axis over the mesh)
-                    vs, res = step(
-                        stack(lambda s: s.store.src),
-                        stack(lambda s: s.store.dst),
-                        stack(lambda s: s.store.weight),
-                        stack(lambda s: s.v), cs, lrs, chunks)
+                with tracing.span("service.tick.prepare"):
+                    step, occ, args = self._tick_operands(
+                        gkey, members, mults)
+                with tracing.span("service.tick.launch"):
+                    vs, res = step(*args)
+                # drop the stacked operands with the call, or they stay
+                # alive next to the next invocation's (a higher peak)
+                del args
                 self._tick_invocations += 1
                 # device work is what the hardware executes: every occupancy
                 # slot rides the longest member's chunk budget (short-budget
@@ -952,26 +957,28 @@ class StreamingService:
                 self._device_work += occ * cfg.steps_per_tick * max_mult
                 if max_mult > 1:
                     self._multiplied_ticks += 1
-                res = np.asarray(res)
-                for i, sess in enumerate(members):
-                    prev = sess.residual
-                    sess.v = vs[i]
-                    sess.residual = float(res[i])
-                    # fresh decay estimate over the member's OWN executed
-                    # step count (its panel froze after its chunk budget);
-                    # a non-contracting observation resets the forecast
-                    # (the scheduler then stays at plain ticks until
-                    # contraction re-establishes)
-                    sess.rate = program.contraction_rate(
-                        prev, sess.residual,
-                        cfg.steps_per_tick * int(mults[i]))
-                    sess.ticks += 1
-                    out[sess.sid] = sess.residual
-                    if sess.residual <= cfg.tol:
-                        sess.converged = True
-                        st = sess.store
-                        sess.est = updates.anchor_estimate_arrays(
-                            st.src, st.dst, st.weight, sess.v)
+                with tracing.span("service.tick.wait"):
+                    res = np.asarray(res)
+                with tracing.span("service.tick.update"):
+                    for i, sess in enumerate(members):
+                        prev = sess.residual
+                        sess.v = vs[i]
+                        sess.residual = float(res[i])
+                        # fresh decay estimate over the member's OWN
+                        # executed step count (its panel froze after its
+                        # chunk budget); a non-contracting observation
+                        # resets the forecast (the scheduler then stays
+                        # at plain ticks until contraction re-establishes)
+                        sess.rate = program.contraction_rate(
+                            prev, sess.residual,
+                            cfg.steps_per_tick * int(mults[i]))
+                        sess.ticks += 1
+                        out[sess.sid] = sess.residual
+                        if sess.residual <= cfg.tol:
+                            sess.converged = True
+                            st = sess.store
+                            sess.est = updates.anchor_estimate_arrays(
+                                st.src, st.dst, st.weight, sess.v)
         return out
 
     @property
