@@ -160,7 +160,7 @@ def _solve_rows():
 def _skew_rows():
     """Skew acceptance: on an alpha=2.5 power-law graph (hub blocks
     concentrate half-edges) the CSR chunk layout — per-block chunk
-    counts, ONE pow2 snap of the total — must walk >= 2x fewer padded
+    counts, ONE snap of the total — must walk >= 2x fewer padded
     half-edge slots than the legacy uniform layout (every block pays
     the worst bucket's snapped chunk count), and the segment-form
     matvec over the SAME layout arrays gets faster in proportion.  The

@@ -11,9 +11,11 @@ bucketed by the node-block of the destination u into a CSR-style
 VARIABLE-chunks-per-block layout: each block owns ceil(bucket / BE)
 chunks (min 1), a flat chunk->block index map steers the kernel's
 scalar-prefetched BlockSpecs, and only the TOTAL chunk count is
-pow2-snapped so graphs of similar size share one compiled program (the
-streaming store's capacity-class economics) without paying the old
-uniform blocks x max-chunks padding on skewed degree distributions.
+snapped (``snap_chunk_count``: powers of two for small layouts, a
+finer grid for large ones) so graphs of similar size share one compiled
+program (the streaming store's capacity-class economics) without paying
+the old uniform blocks x max-chunks padding on skewed degree
+distributions.
 The kernel then works on (block_n, k) panel slices only — see
 kernel.py.  ``build_model_sharded_blocking`` splits the same layout by
 DESTINATION node range so each mesh shard owns its panel rows' output
@@ -78,9 +80,9 @@ class NodeBlocking(NamedTuple):
     The chunk layout is CSR-style: block b owns ``ceil(bucket_b / BE)``
     chunks (min 1 so every block is initialized), laid out contiguously
     in block order; ``chunk_block`` maps chunk -> block for the kernel's
-    scalar-prefetched BlockSpecs.  Padding chunks (total snapped to a
-    power of two) extend the LAST block's run with zero weights, so no
-    block's init/epilogue ever re-fires.
+    scalar-prefetched BlockSpecs.  Padding chunks (total snapped by
+    :func:`snap_chunk_count`) extend the LAST block's run with zero
+    weights, so no block's init/epilogue ever re-fires.
     """
 
     u_local: jax.Array  # (NC*BE,) int32 — dest index local to its block
@@ -90,7 +92,7 @@ class NodeBlocking(NamedTuple):
     deg: jax.Array  # (NB*block_n,) float32 — weighted degrees, row-padded
     block_n: int  # nodes per block (static)
     block_e: int  # half-edges per kernel chunk (static)
-    num_chunks: int  # NC, TOTAL chunks (static, pow2-snapped)
+    num_chunks: int  # NC, TOTAL chunks (static, snap_chunk_count)
     num_nodes: int  # real node count n (static); NB = ceil(n / block_n)
 
     @property
@@ -112,6 +114,29 @@ def next_pow2(x: int) -> int:
     """Smallest power of two >= max(x, 1) — shared by the blocking's
     chunk snapping here and the service's occupancy buckets."""
     return 1 << max(int(np.ceil(np.log2(max(int(x), 1)))), 0)
+
+
+# Total chunk counts below FINE_SNAP_FROM snap to a power of two: the
+# many small tenants of a fleet then share a few compiled programs.  A
+# layout this large is one big tenant, for which that sharing is worth
+# little and pow2 padding costs up to half of every matvec (the kernel's
+# grid, the XLA gather feeding it and its lane pad all walk the padding
+# chunks), so its count rounds up to a multiple of
+# next_pow2(count) // FINE_SNAP_SPLIT instead: less than one such
+# granule of padding, at most eight shapes per octave, and headroom
+# enough that a small edge batch usually keeps the shape.
+FINE_SNAP_FROM = 1024
+FINE_SNAP_SPLIT = 16
+
+
+def snap_chunk_count(nc_raw: int) -> int:
+    """The static total chunk count a layout of ``nc_raw`` live chunks
+    compiles against — the one rule of all three blocking builders."""
+    pow2 = next_pow2(nc_raw)
+    if nc_raw < FINE_SNAP_FROM:
+        return pow2
+    granule = pow2 // FINE_SNAP_SPLIT
+    return -(-nc_raw // granule) * granule
 
 
 def _block_sorted_half_edges(src, dst, weight, block_n: int, nb: int):
@@ -216,17 +241,18 @@ def build_node_blocking(src, dst, weight, num_nodes: int,
     (capacity padding in the streaming store) are DROPPED here: they are
     inert anyway, and keeping them would pile the entire padding into
     node-block 0.  Blocks own ceil(bucket / block_e) chunks each
-    (CSR-style; min 1), and only the TOTAL chunk count is pow2-snapped
-    (`snap_chunks`) so the compile key — and therefore the
-    compiled-program count — stays logarithmic in graph size while
-    skewed buckets no longer inflate every other block's padding.
+    (CSR-style; min 1), and only the TOTAL chunk count is snapped
+    (`snap_chunks`, :func:`snap_chunk_count`) so the compile key — and
+    therefore the compiled-program count — stays logarithmic in graph
+    size while skewed buckets no longer inflate every other block's
+    padding.
     """
     nb = max((num_nodes + block_n - 1) // block_n, 1)
     n_pad = nb * block_n
     u, o, w2, counts = _block_sorted_half_edges(src, dst, weight,
                                                 block_n, nb)
     nc_raw = int(_chunk_counts(counts, block_e).sum())
-    nc = next_pow2(nc_raw) if snap_chunks else nc_raw
+    nc = snap_chunk_count(nc_raw) if snap_chunks else nc_raw
     ul, ot, wt, cb = _fill_chunked(u, o, w2, counts, nb, nc,
                                    block_n, block_e)
     deg = _weighted_degrees(src, dst, weight, n_pad)
@@ -250,8 +276,8 @@ class ShardedNodeBlocking(NamedTuple):
     :func:`repro.core.distributed.pad_edges_for_mesh` contract) and each
     slice is bucketed INDEPENDENTLY by destination node-block, exactly
     like :func:`build_node_blocking` does for the whole buffer.  All
-    shards share ONE static layout — the chunk count is pow2-snapped to
-    the worst shard — so the stacked arrays drop straight into a
+    shards share ONE static layout — the chunk count is snapped from
+    the worst shard's — so the stacked arrays drop straight into a
     ``shard_map`` with the shard axis partitioned over the mesh's edge
     axes, and every shard compiles against identical shapes.
 
@@ -282,6 +308,11 @@ class ShardedNodeBlocking(NamedTuple):
     @property
     def padded_nodes(self) -> int:
         return self.deg.shape[1]
+
+    @property
+    def padded_half_edges(self) -> int:
+        """Total half-edge slots across shards."""
+        return self.num_shards * self.num_chunks * self.block_e
 
     def shard(self, s: int) -> NodeBlocking:
         """Single-shard view — what one mesh device computes with."""
@@ -329,8 +360,8 @@ def build_sharded_node_blocking(src, dst, weight, num_nodes: int,
     with :func:`repro.core.distributed.pad_edges_for_mesh` first); shard
     ``s`` owns the ``s``-th contiguous slice, matching how a
     ``P(edge_axes)`` sharding splits the same buffer on the mesh.  The
-    chunk count is resolved ONCE across shards (max bucket anywhere,
-    pow2-snapped), so an all-padding shard still materializes the shared
+    chunk count is resolved ONCE across shards (the worst shard's,
+    snapped), so an all-padding shard still materializes the shared
     layout — all zero weights and zero degrees — instead of a
     shape-mismatched empty one.
     """
@@ -358,7 +389,7 @@ def build_sharded_node_blocking(src, dst, weight, num_nodes: int,
     # the compile key stable under admission-time edge balance wobble.
     nc_raw = max(int(_chunk_counts(counts, block_e).sum())
                  for _, _, _, counts in shards)
-    nc = next_pow2(nc_raw) if snap_chunks else nc_raw
+    nc = snap_chunk_count(nc_raw) if snap_chunks else nc_raw
     ul = np.zeros((num_shards, nc * block_e), np.int32)
     ot = np.zeros((num_shards, nc * block_e), np.int32)
     wt = np.zeros((num_shards, nc * block_e), np.float32)
@@ -398,7 +429,7 @@ class ModelShardedBlocking(NamedTuple):
     ship rows + gram in ONE fused collective (see
     ``core.program.build_tick_model_sharded``).  Skew is absorbed by
     the CSR chunk layout: a shard owning hub nodes simply has more live
-    chunks, and the shared pow2-snapped total keeps shapes identical
+    chunks, and the shared snapped total keeps shapes identical
     across shards (a hub shard pads less, a sparse shard pads more).
 
     ``u_local``/``chunk_block`` are local to the shard's own blocks;
@@ -414,7 +445,7 @@ class ModelShardedBlocking(NamedTuple):
     deg: jax.Array  # (S, R) float32 — full degrees of the shard's rows
     block_n: int  # static
     block_e: int  # static
-    num_chunks: int  # NC, shared across shards (static, pow2-snapped)
+    num_chunks: int  # NC, shared across shards (static, snapped)
     num_nodes: int  # real node count n (static)
     num_shards: int  # S (static)
 
@@ -478,13 +509,13 @@ def build_model_sharded_blocking(src, dst, weight, num_nodes: int,
                                                 block_n, nb_total)
     deg_full = _weighted_degrees(src, dst, weight, n_pad)
     offs = np.concatenate([[0], np.cumsum(counts)])
-    # shared pow2-snapped total chunk count: identical static shapes per
+    # shared snapped total chunk count: identical static shapes per
     # shard; a hub-heavy shard uses more live chunks, not a new shape
     nc_raw = max(
         int(_chunk_counts(counts[s * nb_per:(s + 1) * nb_per],
                           block_e).sum())
         for s in range(num_shards))
-    nc = next_pow2(nc_raw) if snap_chunks else nc_raw
+    nc = snap_chunk_count(nc_raw) if snap_chunks else nc_raw
     ul = np.zeros((num_shards, nc * block_e), np.int32)
     ot = np.zeros((num_shards, nc * block_e), np.int32)
     wt = np.zeros((num_shards, nc * block_e), np.float32)

@@ -291,6 +291,7 @@ class Server:
                 "tick_invocations": svc.tick_invocations,
                 "device_work": svc.device_work,
                 "multiplied_ticks": svc.multiplied_ticks,
+                "layout_pad_share": svc.layout_pad_share,
             }
         return snap
 

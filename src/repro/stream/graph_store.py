@@ -293,8 +293,9 @@ def node_blocking(store: GraphStore, *, block_n: int = 512,
     edge mutations invalidate it — rebuild after ``apply_edge_batch``.
     Free slots are dropped during bucketing (they are inert and would
     otherwise pile into node-block 0), so the layout's chunk count
-    tracks the LIVE edge count, snapped to powers of two: sessions of
-    one capacity class with similar skew share one compiled program.
+    tracks the LIVE edge count, snapped (``ops.snap_chunk_count``):
+    sessions of one capacity class with similar skew share one compiled
+    program.
     """
     from repro.core import backend as backend_mod
 
@@ -314,7 +315,7 @@ def sharded_node_blocking(store: GraphStore, num_shards: int,
     The buffer's capacity must divide evenly into ``num_shards`` — the
     balance invariant admission/growth maintain via
     ``stream.sharded.balanced_capacity``.  Each shard's contiguous slice
-    is bucketed independently with ONE shared pow2-snapped chunk count,
+    is bucketed independently with ONE shared snapped chunk count,
     so all shards (and all similar-skew sessions of a capacity class)
     compile against the same shapes; an all-padding slice yields an
     all-zero layout contributing exact zeros to the psum.

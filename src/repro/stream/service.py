@@ -178,7 +178,8 @@ class ServiceConfig:
     # into its epilogue; the per-session blocking is built on admission
     # and rebuilt after edge updates (graph_store.node_blocking), and
     # sessions group by (capacity class, degree, blocking layout) — the
-    # chunk count is pow2-snapped, so compile counts stay logarithmic.
+    # chunk count is snapped (es_ops.snap_chunk_count), so compile
+    # counts stay logarithmic.
     backend: str = "auto"
     tick_block_n: int = 512  # node-block rows per VMEM panel slice
     # Device mesh for SHARDED serving (core.program sharded builders):
@@ -338,6 +339,10 @@ class StreamingService:
         self._tick_invocations = 0
         self._device_work = 0
         self._multiplied_ticks = 0  # invocations the scheduler stretched
+        # over the layouts _ensure_blocking builds: live half-edges and
+        # the slots the kernel walks for them (live + chunk padding)
+        self._layout_half_edges = 0
+        self._layout_slots = 0
         # per-class degree map memo: degrees only move on admission /
         # eviction / re-plans, so status sweeps (session_info per
         # tenant) must not rebuild the map per session — O(S^2) fleets
@@ -767,7 +772,12 @@ class StreamingService:
                 sess.store, block_n=block_n)
         if getattr(sess, attr) is None:
             with tracing.span("service.layout"):
-                setattr(sess, attr, build())
+                b = build()
+                setattr(sess, attr, b)
+            # the builder pulled the weights to the host: no device read
+            live = np.count_nonzero(np.asarray(sess.store.weight))
+            self._layout_half_edges += 2 * int(live)
+            self._layout_slots += b.padded_half_edges
 
     def _group_key(self, sess: _Session, degrees: dict | None = None
                    ) -> tuple:
@@ -775,7 +785,7 @@ class StreamingService:
 
         Groups by capacity class + scheduled dilation degree; pallas
         additionally groups by the blocking's static layout (block size
-        and pow2-snapped chunk count), since those are the shapes the
+        and snapped chunk count), since those are the shapes the
         kernel compiles against — sharded pallas uses the per-shard
         layout's statics the same way.  Only ACTIVE (unconverged)
         sessions are ever grouped, so a converged session's invalidated
@@ -845,6 +855,16 @@ class StreamingService:
         """Invocations the residual-decay scheduler stretched past one
         plain tick (traced chunk multiplier > 1 — zero extra compiles)."""
         return self._multiplied_ticks
+
+    @property
+    def layout_pad_share(self) -> float:
+        """Share of the half-edge slots in the blocked layouts built so
+        far that are padding (empty chunk tails and snapped-count padding
+        chunks): what the kernel, its gather and its lane pad walk for
+        nothing.  0.0 before any layout is built."""
+        if not self._layout_slots:
+            return 0.0
+        return 1.0 - self._layout_half_edges / self._layout_slots
 
     def _tick_multipliers(self, members: list[_Session]) -> np.ndarray:
         """Residual-decay scheduling: PER-SESSION steps multipliers.

@@ -1,6 +1,7 @@
 """Serving layer: SLO metrics, the versioned results store, the
 double-buffered async ingest/tick pipeline (including the threaded
 concurrency suite), and the stdlib HTTP front end."""
+import dataclasses
 import json
 import threading
 import time
@@ -171,6 +172,25 @@ def test_server_pipeline_manual_steps_end_to_end():
     srv.push("b", [[0, 1]], [1.0])
     srv.evict("b")
     assert m.counter("dropped_batches") == 1
+
+
+def test_server_stats_layout_pad_share():
+    """The engine reports the padding share of the blocked layouts the
+    pallas tick path built: 1 - live half-edges / slots walked."""
+    cfg = dataclasses.replace(SERVE_SVC, backend="pallas", tick_block_n=32)
+    srv = Server(ServerConfig(service=cfg))
+    assert srv.stats()["engine"]["layout_pad_share"] == 0.0
+    edges, w, n, _ = _sbm_edges(11)
+    srv.admit("a", edges, n, weights=w, num_clusters=3, edge_capacity=1024)
+    for _ in range(50):
+        srv.step()
+        if srv.service.all_converged:
+            break
+    assert srv.service.all_converged
+    share = srv.stats()["engine"]["layout_pad_share"]
+    slots = srv.service._sessions["a"].blocking.padded_half_edges
+    assert 0.0 < share < 1.0
+    assert share == pytest.approx(1.0 - 2 * len(edges) / slots)
 
 
 def test_server_serialized_pipeline_applies_inline():

@@ -2,10 +2,10 @@
 
 The CSR chunk layout (``ops.build_node_blocking``: blocks own
 ``ceil(bucket / block_e)`` chunks, only the TOTAL chunk count is
-pow2-snapped, and a scalar-prefetched chunk->block map drives the
-kernel) exists for skewed degree distributions — power-law graphs whose
-hub blocks would otherwise inflate every block to the worst bucket's
-padding.  This file drives power-law samples through the layout and
+snapped (pow2 at these sizes), and a scalar-prefetched chunk->block map
+drives the kernel) exists for skewed degree distributions — power-law
+graphs whose hub blocks would otherwise inflate every block to the worst
+bucket's padding.  This file drives power-law samples through the layout and
 asserts the structural contracts the kernels rely on:
 
   * every live half-edge is materialized exactly once, at its
@@ -282,6 +282,72 @@ def test_model_all_padding_shard_inert():
                 use_kernel=use_kernel, interpret=True))
             assert not np.isnan(out).any()
             np.testing.assert_array_equal(out, 0.0)
+
+
+def _path_layout(builder: str, nc_raw: int, snap: bool):
+    """A layout of exactly ``nc_raw`` raw chunks from a tiny graph: one
+    node per block (block_n=1), each owning one chunk (degree <= 2 <
+    block_e), so the count comes from the node count, not the edges."""
+    kw = dict(block_n=1, block_e=8, snap_chunks=snap)
+    n = 2 * nc_raw if builder == "model" else nc_raw  # 2 shards' rows
+    m = 64  # a path over the first nodes: an even edge count
+    src = np.arange(m, dtype=np.int64)
+    dst = src + 1
+    w = np.ones(m, np.float32)
+    if builder == "single":
+        return es_ops.build_node_blocking(src, dst, w, n, **kw)
+    if builder == "edge":
+        return es_ops.build_sharded_node_blocking(src, dst, w, n, 2, **kw)
+    return es_ops.build_model_sharded_blocking(src, dst, w, n, 2, **kw)
+
+
+@pytest.mark.parametrize("builder", ["single", "edge", "model"])
+@pytest.mark.parametrize("nc_raw", [1023, 1024, 1025, 2121, 70_000])
+def test_chunk_count_snapping(builder, nc_raw):
+    """All three builders snap the total chunk count by one rule: the
+    next power of two below FINE_SNAP_FROM raw chunks, from there a
+    multiple of next_pow2 // FINE_SNAP_SPLIT, less than one granule
+    above the raw count."""
+    raw = _path_layout(builder, nc_raw, snap=False).num_chunks
+    assert raw == nc_raw
+    nc = _path_layout(builder, nc_raw, snap=True).num_chunks
+    assert nc == es_ops.snap_chunk_count(raw)
+    pow2 = es_ops.next_pow2(raw)
+    if raw < es_ops.FINE_SNAP_FROM:
+        assert nc == pow2
+    else:
+        granule = pow2 // es_ops.FINE_SNAP_SPLIT
+        assert nc % granule == 0
+        assert raw <= nc < raw + granule
+    if raw == 2121:  # the dblp_1c layout: 2,304 grid steps, not 4,096
+        assert nc == 2304
+
+
+def test_padding_chunks_bitwise_inert():
+    """The blocked matvec is bitwise the same whatever count of padding
+    chunks trails the layout: raw, pow2-snapped, or raw + 3."""
+    rng = np.random.default_rng(3)
+    src, dst, w, n, block_n = _skewed_case(3)
+    block_e = 128
+    v = jnp.asarray(rng.normal(size=(n, 6)), jnp.float32)
+    raw = es_ops.build_node_blocking(src, dst, w, n, block_n=block_n,
+                                     snap_chunks=False)
+    pow2 = es_ops.build_node_blocking(src, dst, w, n, block_n=block_n)
+    nb = raw.num_blocks
+    u, o, w2, counts = es_ops._block_sorted_half_edges(src, dst, w,
+                                                       block_n, nb)
+    nc = raw.num_chunks + 3
+    assert nc not in (raw.num_chunks, pow2.num_chunks)
+    ul, ot, wt, cb = es_ops._fill_chunked(u, o, w2, counts, nb, nc,
+                                          block_n, block_e)
+    odd = raw._replace(u_local=jnp.asarray(ul), other=jnp.asarray(ot),
+                       weight=jnp.asarray(wt), chunk_block=jnp.asarray(cb),
+                       num_chunks=nc)
+    outs = [np.asarray(es_ops.edge_spmm_blocked(b, v, interpret=True))
+            for b in (raw, pow2, odd)]
+    for out in outs[1:]:
+        np.testing.assert_array_equal(out.view(np.uint32),
+                                      outs[0].view(np.uint32))
 
 
 if HAVE_HYPOTHESIS:
