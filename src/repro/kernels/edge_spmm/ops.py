@@ -583,14 +583,14 @@ def model_local_rows(u_local, other, weight, chunk_block, deg,
     rows = deg.shape[0]
     v = v.astype(jnp.float32)
     if use_kernel:
-        pad_k = (-k) % 128
+        pad_k = (-k) % kernel.LANES
         vp = jnp.pad(v, ((0, padded_nodes - n), (0, pad_k)))
         gathered = vp[other]  # (NC*BE, kp) global gather
         v_rows = jax.lax.dynamic_slice(
             vp, (row_start, 0), (rows, k + pad_k))
         out = kernel.edge_spmm_nb(
             u_local, weight, gathered, chunk_block, deg, v_rows, ab,
-            block_n=block_n, block_e=block_e,
+            k=k, block_n=block_n, block_e=block_e,
             num_chunks=num_chunks, interpret=interpret)
         return out[:, :k]
     vp = jnp.pad(v, ((0, padded_nodes - n), (0, 0)))
@@ -610,12 +610,12 @@ def _edge_spmm_blocked(u_local, other, weight, chunk_block, deg, v, ab,
                        num_chunks: int, interpret: bool):
     n, k = v.shape
     n_pad = deg.shape[0]
-    pad_k = (-k) % 128
+    pad_k = (-k) % kernel.LANES
     vp = jnp.pad(v.astype(jnp.float32), ((0, n_pad - n), (0, pad_k)))
     gathered = vp[other]  # (NC*BE, kp) XLA gather; the scatter is MXU
     out = kernel.edge_spmm_nb(
         u_local, weight, gathered, chunk_block, deg, vp, ab,
-        block_n=block_n, block_e=block_e,
+        k=k, block_n=block_n, block_e=block_e,
         num_chunks=num_chunks, interpret=interpret)
     return out[:n, :k]
 

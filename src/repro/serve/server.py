@@ -292,6 +292,9 @@ class Server:
                 "device_work": svc.device_work,
                 "multiplied_ticks": svc.multiplied_ticks,
                 "layout_pad_share": svc.layout_pad_share,
+                # MXU passes per chunk of the node-blocked kernel the
+                # tick runs: one at every panel width (0: no kernel)
+                "scatter_passes": int(svc.backend == "pallas"),
             }
         return snap
 

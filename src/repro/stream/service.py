@@ -1005,6 +1005,12 @@ class StreamingService:
     def all_converged(self) -> bool:
         return all(s.converged for s in self._sessions.values())
 
+    @property
+    def backend(self) -> str:
+        """The resolved SpMM backend the tick runs ("pallas" or
+        "segment")."""
+        return self._backend
+
     def run_until_converged(self, max_ticks: int = 500) -> int:
         """Tick until every session converges; returns ticks used.
 

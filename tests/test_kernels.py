@@ -4,6 +4,7 @@ Shape/dtype sweeps + hypothesis property tests per kernel, as required:
 every kernel asserts allclose against its ref.py oracle.
 """
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -147,6 +148,103 @@ def test_edge_spmm_node_blocked_property(seed):
     # half-edges subtract w each
     want = es_ref.edge_spmm(src, dst, w, v)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def _laplacian_f64(src, dst, w, v):
+    """float64 L v over the edge list, and per element the sum of its
+    terms' magnitudes: deg_u |v_u| + sum_o w |v_o|."""
+    w64, v64 = w.astype(np.float64), v.astype(np.float64)
+    deg = np.zeros(len(v))
+    np.add.at(deg, src, w64)
+    np.add.at(deg, dst, w64)
+    lv = deg[:, None] * v64
+    terms = deg[:, None] * np.abs(v64)
+    for a, b in ((src, dst), (dst, src)):
+        np.add.at(lv, a, -w64[:, None] * v64[b])
+        np.add.at(terms, a, w64[:, None] * np.abs(v64[b]))
+    return lv, terms
+
+
+def _one_bf16_pass(nb, v):
+    """The former scatter at one default-precision MXU pass, as the TPU
+    runs it: the weighted one-hot and the gathered rows rounded to bf16,
+    exact products, float32 sums."""
+    def bf16(x):
+        return np.asarray(x, np.float32).astype(jnp.bfloat16).astype(
+            np.float32)
+    n, k = v.shape
+    vp = np.zeros((nb.padded_nodes, k), np.float32)
+    vp[:n] = v
+    blk = np.repeat(np.asarray(nb.chunk_block)[:-1], nb.block_e)
+    dest = blk * nb.block_n + np.asarray(nb.u_local)
+    out = np.asarray(nb.deg)[:, None] * vp
+    np.subtract.at(out, dest, bf16(nb.weight)[:, None]
+                   * bf16(vp[np.asarray(nb.other)]))
+    return out[:n]
+
+
+# k = 6 stacks the data's three bf16 parts into 24 operand rows; k = 48
+# into 144, more than one 128-row MXU tile
+@pytest.mark.parametrize("k", [6, 48])
+def test_edge_spmm_node_blocked_exact_to_f32(k):
+    """The blocked kernel's bf16 passes keep float32: each element is
+    within 8 eps32 of the float64 result, scaled by its terms' sum.
+    One bf16 pass of the weighted one-hot misses that bound."""
+    rng = np.random.default_rng(k)
+    n, e, block_n = 200, 1500, 32
+    src = rng.integers(0, n, e)
+    hub = rng.uniform(size=e) < 0.4  # three hubs of node-block 0
+    src[hub] = rng.integers(0, 3, hub.sum())
+    dst = rng.integers(0, n, e)
+    proper = src != dst
+    src, dst = src[proper], dst[proper]
+    w = rng.uniform(0, 2, len(src)).astype(np.float32)
+    v = rng.normal(size=(n, k)).astype(np.float32)
+    nb = es_ops.build_node_blocking(src, dst, w, n, block_n=block_n)
+    assert np.bincount(np.asarray(nb.chunk_block)[:-1]).max() >= 4
+    got = np.asarray(es_ops.edge_spmm_blocked(nb, jnp.asarray(v), **I))
+    want, terms = _laplacian_f64(src, dst, w, v)
+    bound = 8 * np.finfo(np.float32).eps * terms
+    assert np.all(np.abs(got - want) <= bound)
+    control = _one_bf16_pass(nb, v)
+    assert np.mean(np.abs(control - want) > bound) > 0.5
+
+
+def _eqns(jaxpr, name: str) -> list:
+    """The equations of primitive ``name`` in a jaxpr, nested ones
+    included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    found += _eqns(sub, name)
+    return found
+
+
+@pytest.mark.parametrize("k", [6, 48])
+def test_edge_spmm_node_blocked_one_dot_per_chunk(k):
+    """Every chunk of the blocked kernel makes one dot, on bf16
+    operands, at any panel width: the data's three bf16 parts go
+    through the MXU stacked."""
+    rng = np.random.default_rng(k)
+    n = 100
+    src, dst = rng.integers(0, n, 400), rng.integers(0, n, 400)
+    proper = src != dst
+    src, dst = src[proper], dst[proper]
+    w = rng.uniform(0, 2, len(src)).astype(np.float32)
+    nb = es_ops.build_node_blocking(src, dst, w, n, block_n=32)
+    v = jnp.zeros((n, k), jnp.float32)
+    closed = jax.make_jaxpr(
+        lambda v: es_ops.edge_spmm_blocked(nb, v, **I))(v)
+    calls = _eqns(closed.jaxpr, "pallas_call")
+    assert len(calls) == 1
+    dots = _eqns(calls[0].params["jaxpr"], "dot_general")
+    assert len(dots) == 1
+    assert all(x.aval.dtype == jnp.bfloat16 for x in dots[0].invars)
 
 
 # --- node-blocking layout properties (single-device + per-shard) ----------

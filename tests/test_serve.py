@@ -193,6 +193,25 @@ def test_server_stats_layout_pad_share():
     assert share == pytest.approx(1.0 - 2 * len(edges) / slots)
 
 
+@pytest.mark.parametrize("k", [6, 48])
+def test_server_stats_scatter_passes(k):
+    """The engine reports the MXU passes per chunk of the node-blocked
+    kernel its pallas tick runs: one, also where the data's three bf16
+    parts stack into more than 128 rows (k = 48); a segment tick runs
+    no kernel."""
+    cfg = dataclasses.replace(SERVE_SVC, backend="pallas", k=k,
+                              tick_block_n=32)
+    srv = Server(ServerConfig(service=cfg))
+    edges, w, n, _ = _sbm_edges(13, n=64)
+    srv.admit("a", edges, n, weights=w, num_clusters=3, edge_capacity=1024)
+    assert srv.step()
+    assert srv.service._sessions["a"].blocking is not None
+    assert srv.stats()["engine"]["scatter_passes"] == 1
+    seg = Server(ServerConfig(service=dataclasses.replace(
+        SERVE_SVC, backend="segment", k=k)))
+    assert seg.stats()["engine"]["scatter_passes"] == 0
+
+
 def test_server_serialized_pipeline_applies_inline():
     srv = Server(ServerConfig(service=SERVE_SVC, pipeline="serialized"))
     edges, w, n, _ = _sbm_edges(12)

@@ -65,7 +65,10 @@ def test_edge_spmm_one_hot_compiles_at_node_limit(spec):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_edge_spmm_node_blocked_compiles_at_large_tenant(spec):
+# k = 6 stacks the three bf16 parts of the data into one MXU pass;
+# k = 48 (3 * 48 rows > 128) takes three passes
+@pytest.mark.parametrize("k", [K, 48])
+def test_edge_spmm_node_blocked_compiles_at_large_tenant(spec, k):
     slots = NUM_CHUNKS * BLOCK_E
     compiled = _compile(
         lambda ul, ot, w, cb, deg, v, ab: es_ops._edge_spmm_blocked(
@@ -73,7 +76,7 @@ def test_edge_spmm_node_blocked_compiles_at_large_tenant(spec):
             num_chunks=NUM_CHUNKS, interpret=False),
         spec((slots,), jnp.int32), spec((slots,), jnp.int32),
         spec((slots,)), spec((NUM_CHUNKS + 1,), jnp.int32),
-        spec((NODE_CAP,)), spec((NODE_CAP, K)), spec((2,)))
+        spec((NODE_CAP,)), spec((NODE_CAP, k)), spec((2,)))
     assert "tpu_custom_call" in compiled.as_text()
 
 
