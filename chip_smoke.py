@@ -193,7 +193,6 @@ def one_chip(clock: PhaseClock) -> None:
     from repro.core import backend as backend_mod
     from repro.core import graphs, program
     from repro.serve import Server, ServerConfig
-    from repro.stream import graph_store as gs
 
     with clock.phase("generate"):
         big_edges, big_truth = big_graph()
@@ -279,7 +278,7 @@ def one_chip(clock: PhaseClock) -> None:
     with clock.phase("check_c_pallas_tick"):
         sess = svc._get("big")  # the served session's own state
         degree = summaries["big"]["degree"]
-        blocking = gs.node_blocking(sess.store, block_n=cfg.tick_block_n)
+        blocking = svc._source.build(sess.store)  # single-device layout
         schedule = program.StepSchedule(method=cfg.method, degree=degree,
                                         steps=cfg.steps_per_tick,
                                         backend="pallas")
@@ -362,7 +361,7 @@ def four_chips(clock: PhaseClock) -> None:
 
     svc = services["panel_sharded"]
     sess = svc._get("big")
-    mb = sess.model_blocking
+    mb = sess.layout
     cfg = svc.cfg
     degree = svc.session_info("big")["degree"]
     tick = program.build_tick_model_sharded(

@@ -28,7 +28,7 @@ def default_edge_mesh(max_shards: int | None = None,
     ``max_shards`` local devices (all of them by default).
 
     Every edge-parallel entry point in this repo (`core.distributed`,
-    `stream.sharded`, the distributed test lane and benchmarks) shards
+    `stream.source`, the distributed test lane and benchmarks) shards
     edges over "data"; this helper builds that mesh from however many
     devices the process sees — 1 in plain tier-1 runs, 8 under the CI
     lane's ``XLA_FLAGS=--xla_force_host_platform_device_count=8``, 4 on

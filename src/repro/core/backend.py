@@ -40,7 +40,6 @@ import os
 from typing import Callable
 
 import jax
-import jax.numpy as jnp
 
 from repro.core import laplacian as lap
 from repro.kernels.edge_spmm import ops as es_ops
@@ -121,18 +120,6 @@ def sharded_blocking_for(g: lap.EdgeList, num_shards: int,
     scalable layout for ``distributed.sharded_blocked_matvec`` (the
     sharded pallas path past ``ONE_HOT_NODE_LIMIT``)."""
     return build_sharded_node_blocking(
-        g.src, g.dst, g.weight, g.num_nodes, num_shards,
-        block_n=block_n or DEFAULT_BLOCK_N, block_e=block_e)
-
-
-def model_blocking_for(g: lap.EdgeList, num_shards: int,
-                       *, block_n: int | None = None,
-                       block_e: int = 128) -> ModelShardedBlocking:
-    """Destination-aligned per-shard layouts for PANEL sharding — shard
-    ``s`` owns rows ``[s * R, (s + 1) * R)`` of the (n, k) panel and all
-    half-edges destined there (``program.build_tick_model_sharded``'s
-    layout; works for both the kernel and segment row computations)."""
-    return build_model_sharded_blocking(
         g.src, g.dst, g.weight, g.num_nodes, num_shards,
         block_n=block_n or DEFAULT_BLOCK_N, block_e=block_e)
 

@@ -2,7 +2,7 @@
 
 Before this module the repo carried four hand-rolled copies of the same
 iteration — ``core.solvers.run_solver``'s eval loop, the streaming
-service's segment and pallas tick builders, and ``stream.sharded``'s
+service's segment and pallas tick builders, and the sharded service's
 shard_mapped tick programs — so every convergence improvement (adaptive
 lr, probe-driven degrees, smarter stopping) had to be implemented four
 times or not at all.  The builders here own the composition
@@ -11,10 +11,10 @@ times or not at all.  The builders here own the composition
 
 as one compiled unit, parameterized along three axes:
 
-* **operator source** — raw edge arrays (segment gather/scatter), a
-  node-blocked pallas layout with the dilation AXPY fused into the
-  kernel epilogue, or per-shard sharded layouts whose matvecs psum
-  under ``shard_map``;
+* **operator source** (chosen once per service, :mod:`repro.stream
+  .source`) — raw edge arrays (segment gather/scatter), a node-blocked
+  pallas layout with the dilation AXPY fused into the kernel epilogue,
+  or per-shard sharded layouts whose matvecs psum under ``shard_map``;
 * **batching shape** — a single panel (`run_chunk`, `run_program`), a
   vmapped/``lax.map``-ped session group (`build_tick_program` without a
   mesh), or a shard_mapped capacity class (`build_tick_program` with a
@@ -393,6 +393,30 @@ def _mapped_step(step_fn):
     return step_all
 
 
+def _segment_opv_all(src, dst, w, cs, degree: int, edge_axes=None):
+    """Group dilated operator over stacked (G, cap) edge buffers.
+
+    Without ``edge_axes`` it is ``operators.dilated_operator_arrays``
+    vmapped over the group (the summation order the CPU tests compare);
+    with it each device holds a slice of every buffer, and every matvec
+    psums the stacked panels before the AXPY.
+    """
+    if edge_axes is None:
+        return lambda us: jax.vmap(
+            lambda s, d, wt, x, c:
+            operators.dilated_operator_arrays(s, d, wt, c, degree)(x)
+        )(src, dst, w, us, cs)
+    local_mv = jax.vmap(lap.edge_matvec_arrays)
+
+    def opv_all(us):
+        def body(_, xs):
+            lxs = _psum(local_mv(src, dst, w, xs), edge_axes)
+            return xs - cs[:, None, None] * lxs
+        return jax.lax.fori_loop(0, degree, body, us)
+
+    return opv_all
+
+
 def _blocked_opv_all(u_local, other, w, cb, deg, cs, degree: int,
                      block_n: int, num_chunks: int, block_e: int,
                      interpret: bool, edge_axes=None):
@@ -436,117 +460,6 @@ def _blocked_opv_all(u_local, other, w, cb, deg, cs, degree: int,
         return jax.lax.fori_loop(0, degree, body, us)
 
     return opv_all
-
-
-def build_tick_segment(schedule: StepSchedule):
-    """Single-device segment tick: fn(src, dst, w, vs, cs, lrs, chunks).
-
-    Inputs are the group's stacked (G, cap) edge buffers, (G, n, k)
-    panels, traced per-session (G,) dilation scales / learning rates,
-    and the traced chunk multiplier; one compiled program per
-    (schedule statics, shapes).
-    """
-    step_fn = solvers.STEP_FNS[schedule.method]
-    degree, steps = schedule.degree, schedule.steps
-
-    def tick(src, dst, w, vs, cs, lrs, chunks):
-        def opv_all(us):
-            return jax.vmap(
-                lambda s, d, wt, x, c:
-                operators.dilated_operator_arrays(s, d, wt, c, degree)(x)
-            )(src, dst, w, us, cs)
-
-        return _group_loop(opv_all, _vmapped_step(step_fn), vs, lrs,
-                           steps, chunks)
-
-    return jax.jit(tick)
-
-
-def build_tick_pallas(schedule: StepSchedule, block_n: int,
-                      num_chunks: int, block_e: int):
-    """Single-device pallas tick:
-    fn(u_local, other, w, cb, deg, vs, cs, lrs, chunks).
-
-    The dilated matvec runs the node-blocked incidence-SpMM kernel with
-    the dilation AXPY (alpha=-c, beta=1) fused into its epilogue, and
-    the solver step uses the fused mu-EG kernel; sessions advance under
-    ``lax.map`` (pallas grids don't vmap across the session axis).
-    ``cb`` is the stacked (G, NC+1) chunk->block map steering the
-    kernel's scalar-prefetched BlockSpecs.
-    """
-    interp = backend_mod.kernel_interpret()
-    step_fn = solvers.make_step_fn(schedule.method, "pallas")
-    degree, steps = schedule.degree, schedule.steps
-
-    def tick(u_local, other, w, cb, deg, vs, cs, lrs, chunks):
-        opv_all = _blocked_opv_all(u_local, other, w, cb, deg, cs, degree,
-                                   block_n, num_chunks, block_e,
-                                   interp)
-        return _group_loop(opv_all, _mapped_step(step_fn), vs, lrs,
-                           steps, chunks)
-
-    return jax.jit(tick)
-
-
-def build_tick_sharded_segment(schedule: StepSchedule, mesh, edge_axes):
-    """Sharded segment tick: the group's stacked (G, cap) edge buffers
-    shard over ``edge_axes`` along the capacity axis; each dilation step
-    is the per-shard vmapped gather/scatter + ONE psum of the stacked
-    (G, n, k) panels (same decomposition as PR 4's tick programs)."""
-    step_fn = solvers.STEP_FNS[schedule.method]
-    degree, steps = schedule.degree, schedule.steps
-    spec_e = P(None, edge_axes)
-
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(spec_e, spec_e, spec_e, P(), P(), P(), P()),
-        out_specs=(P(), P()),
-        check_vma=False)  # scan carries mix varying/unvarying values
-    def tick(src, dst, w, vs, cs, lrs, chunks):
-        local_mv = jax.vmap(lap.edge_matvec_arrays)
-
-        def opv_all(us):
-            def body(_, xs):
-                lxs = _psum(local_mv(src, dst, w, xs), edge_axes)
-                return xs - cs[:, None, None] * lxs
-            return jax.lax.fori_loop(0, degree, body, us)
-
-        return _group_loop(opv_all, _vmapped_step(step_fn), vs, lrs,
-                           steps, chunks)
-
-    return jax.jit(tick)
-
-
-def build_tick_sharded_pallas(schedule: StepSchedule, mesh, edge_axes,
-                              block_n: int, num_chunks: int,
-                              block_e: int):
-    """Sharded pallas tick: per-shard node-blocked kernels + one psum.
-
-    fn(u_local, other, w, cb, deg, vs, cs, lrs, chunks) with (G, S, ...)
-    stacked per-shard layouts sharded over ``edge_axes`` along the
-    shard axis; the AXPY applies post-psum (beta must apply exactly
-    once, so the kernel-epilogue fusion is single-device-only) and the
-    solver step maps the fused mu-EG kernel under ``lax.map``.
-    """
-    interp = backend_mod.kernel_interpret()
-    step_fn = solvers.make_step_fn(schedule.method, "pallas")
-    degree, steps = schedule.degree, schedule.steps
-    spec_b = P(None, edge_axes)
-
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(spec_b, spec_b, spec_b, spec_b, spec_b,
-                  P(), P(), P(), P()),
-        out_specs=(P(), P()),
-        check_vma=False)  # pallas_call has no replication rule
-    def tick(u_local, other, w, cb, deg, vs, cs, lrs, chunks):
-        opv_all = _blocked_opv_all(u_local, other, w, cb, deg, cs, degree,
-                                   block_n, num_chunks, block_e,
-                                   interp, edge_axes=edge_axes)
-        return _group_loop(opv_all, _mapped_step(step_fn), vs, lrs,
-                           steps, chunks)
-
-    return jax.jit(tick)
 
 
 def num_model_shards(mesh, model_axes=("model",)) -> int:
@@ -692,19 +605,29 @@ def build_tick_model_sharded(schedule: StepSchedule, mesh, model_axes,
 
 def build_tick_program(schedule: StepSchedule, *, layout=None, mesh=None,
                        edge_axes=("data",), model_axes=None):
-    """One compiled batched tick program for a session group.
+    """One compiled batched tick program for a session group:
+    fn(*operands, vs, cs, lrs, chunks).
 
-    ``layout`` is None for the segment operator source or the pallas
-    blocking statics ``(block_n, num_chunks, block_e)``; ``mesh``
-    switches to the shard_mapped variants; ``model_axes`` (with a mesh
-    and a layout) selects the PANEL-sharded tick over destination-
-    aligned layouts (:func:`build_tick_model_sharded` — one fused
-    rows+gram collective per solver step).  The streaming service keys
-    the returned program by its (capacity class, degree, layout,
-    occupancy bucket, schedule statics); the per-session lr/scale AND
-    the scheduler's tick multipliers (scalar or per-session ``(G,)``
-    chunk budgets — see :func:`_group_loop`) are traced inputs — the
-    whole adaptive layer moves underneath one compiled program.
+    ``operands`` are the group's stacked operator source
+    (:mod:`repro.stream.source`): the (G, cap) edge buffers ``src, dst,
+    w`` when ``layout`` is None, else node-blocked layouts ``u_local,
+    other, w, cb, deg`` with the static ``layout = (block_n, num_chunks,
+    block_e)``.  ``vs`` are the (G, n, k) panels, ``cs``/``lrs`` the
+    traced per-session (G,) dilation scales and learning rates, and
+    ``chunks`` the scheduler's traced tick multipliers (see
+    :func:`_group_loop`): the whole adaptive layer moves underneath one
+    compiled program per (schedule statics, layout, shapes).
+
+    The operands pick the matvec and the batching: edge buffers run the
+    segment gather/scatter with the solver step vmapped; a layout runs
+    the node-blocked kernel and the fused mu-EG kernel step under
+    ``lax.map`` (pallas grids don't vmap).  ``mesh`` shard_maps the body
+    with the operands split over ``edge_axes`` along their second
+    (capacity or shard) axis: every dilation matvec psums the stacked
+    panels and the AXPY applies after it, where the single-device kernel
+    fuses it into its epilogue.  ``model_axes`` (with a mesh and a
+    layout) selects the PANEL-sharded tick
+    (:func:`build_tick_model_sharded`).
     """
     if mesh is not None and model_axes is not None:
         if layout is None:
@@ -713,13 +636,35 @@ def build_tick_program(schedule: StepSchedule, *, layout=None, mesh=None,
                 "statics (block_n, num_chunks, block_e)")
         return build_tick_model_sharded(schedule, mesh, model_axes,
                                         *layout)
-    if mesh is not None and layout is not None:
-        return build_tick_sharded_pallas(schedule, mesh, edge_axes, *layout)
+    degree, steps = schedule.degree, schedule.steps
+    interp = backend_mod.kernel_interpret()
+    if layout is None:
+        step_all = _vmapped_step(solvers.STEP_FNS[schedule.method])
+    else:
+        step_all = _mapped_step(
+            solvers.make_step_fn(schedule.method, "pallas"))
+
+    axes = None if mesh is None else edge_axes
+
+    def tick(*args):
+        *ops, vs, cs, lrs, chunks = args
+        if layout is None:
+            opv_all = _segment_opv_all(*ops, cs, degree, axes)
+        else:
+            opv_all = _blocked_opv_all(*ops, cs, degree, *layout, interp,
+                                       edge_axes=axes)
+        return _group_loop(opv_all, step_all, vs, lrs, steps, chunks)
+
     if mesh is not None:
-        return build_tick_sharded_segment(schedule, mesh, edge_axes)
-    if layout is not None:
-        return build_tick_pallas(schedule, *layout)
-    return build_tick_segment(schedule)
+        spec = P(None, edge_axes)
+        tick = shard_map(
+            tick, mesh=mesh,
+            in_specs=(spec,) * (3 if layout is None else 5) + (P(),) * 4,
+            out_specs=(P(), P()),
+            # scan carries mix varying/unvarying values, and pallas_call
+            # has no replication rule
+            check_vma=False)
+    return jax.jit(tick)
 
 
 # ---------------------------------------------------------------------------
@@ -760,11 +705,7 @@ __all__ = [
     "StepSchedule",
     "apply_solver_step",
     "build_tick_model_sharded",
-    "build_tick_pallas",
     "build_tick_program",
-    "build_tick_segment",
-    "build_tick_sharded_pallas",
-    "build_tick_sharded_segment",
     "contraction_rate",
     "count_psums",
     "num_model_shards",

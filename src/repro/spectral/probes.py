@@ -266,7 +266,8 @@ def probe_sharded_edge_arrays(
     num_steps: int = 24,
     backend: str = "segment",
 ) -> ProbeResult:
-    """SLQ over MESH-SHARDED edge buffers (stream.sharded's probe path).
+    """SLQ over MESH-SHARDED edge buffers (the edge-sharded probe path of
+    ``stream.source``).
 
     Semantically identical to :func:`probe_edge_arrays` — same Lanczos
     recurrence, same keys, the matvec is just psum-assembled from edge

@@ -34,12 +34,13 @@ service
     (converged sessions cost zero device work), eviction with panel
     caching (``add_graph(resume_panel=)`` re-admission), streaming
     updates routed through the incremental path, and label serving.
-sharded
-    Mesh-parallel serving policy (``ServiceConfig(mesh=...)``):
-    shard-balanced capacities and the per-shard decomposition contract;
-    the shard_mapped tick programs themselves live in
-    ``repro.core.program`` (one psum of the stacked panels per dilation
-    matvec, sharded admission probes).
+source
+    The service's one operator source, chosen from (backend, mesh,
+    model_axes): segment, node-blocked, edge-sharded segment or
+    blocked, or panel-sharded.  It owns the shard-balanced edge
+    capacities, the session's one cached layout, the group-key
+    statics, the stacked tick operands and the admission probe; the
+    tick programs themselves live in ``repro.core.program``.
 tracking
     Stable cluster ids across re-solves: greedy maximum-overlap matching
     of each new k-means labelling onto the previous one.

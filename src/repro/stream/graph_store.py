@@ -281,68 +281,3 @@ def spectral_radius_upper_bound(store: GraphStore) -> tuple[GraphStore, jax.Arra
     """(refreshed store, 2 * max weighted degree) — the Sec. 5.4 bound."""
     store = refresh_degrees(store)
     return store, 2.0 * jnp.max(store.deg)
-
-
-def node_blocking(store: GraphStore, *, block_n: int = 512,
-                  block_e: int = 128):
-    """Host-side node-blocked half-edge layout of the store's LIVE edges
-    for the pallas matvec backend (repro.core.backend).
-
-    Built once per admission / re-solve and cached alongside the padded
-    buffers by the owner (the streaming service keeps it per session);
-    edge mutations invalidate it — rebuild after ``apply_edge_batch``.
-    Free slots are dropped during bucketing (they are inert and would
-    otherwise pile into node-block 0), so the layout's chunk count
-    tracks the LIVE edge count, snapped (``ops.snap_chunk_count``):
-    sessions of one capacity class with similar skew share one compiled
-    program.
-    """
-    from repro.core import backend as backend_mod
-
-    return backend_mod.build_node_blocking(
-        np.asarray(store.src), np.asarray(store.dst),
-        np.asarray(store.weight), store.num_nodes,
-        block_n=min(block_n, store.num_nodes), block_e=block_e)
-
-
-def sharded_node_blocking(store: GraphStore, num_shards: int,
-                          *, block_n: int = 512, block_e: int = 128):
-    """Per-shard node-blocked layouts of the store's edge buffer for the
-    mesh-parallel pallas tick (stream.sharded) — the sharded sibling of
-    :func:`node_blocking`, cached alongside it by the owner and
-    invalidated the same way (edge mutations stale it).
-
-    The buffer's capacity must divide evenly into ``num_shards`` — the
-    balance invariant admission/growth maintain via
-    ``stream.sharded.balanced_capacity``.  Each shard's contiguous slice
-    is bucketed independently with ONE shared snapped chunk count,
-    so all shards (and all similar-skew sessions of a capacity class)
-    compile against the same shapes; an all-padding slice yields an
-    all-zero layout contributing exact zeros to the psum.
-    """
-    from repro.core import backend as backend_mod
-
-    return backend_mod.build_sharded_node_blocking(
-        np.asarray(store.src), np.asarray(store.dst),
-        np.asarray(store.weight), store.num_nodes, num_shards,
-        block_n=min(block_n, store.num_nodes), block_e=block_e)
-
-
-def model_sharded_blocking(store: GraphStore, num_shards: int,
-                           *, block_n: int = 512, block_e: int = 128):
-    """Destination-aligned per-shard layouts of the store's live edges
-    for the PANEL-sharded tick (``core.program.build_tick_model_sharded``)
-    — shard ``s`` owns a contiguous row range of the eigenvector panel
-    and every half-edge destined there.  Cached/invalidated exactly like
-    :func:`node_blocking`.  No edge-buffer balance contract: any
-    capacity works (skew moves live chunks between shards, not shapes),
-    which is what makes this the layout of choice for million-node
-    single-tenant sessions where the PANEL, not the edge buffer, is the
-    scaling ceiling.
-    """
-    from repro.core import backend as backend_mod
-
-    return backend_mod.build_model_sharded_blocking(
-        np.asarray(store.src), np.asarray(store.dst),
-        np.asarray(store.weight), store.num_nodes, num_shards,
-        block_n=min(block_n, store.num_nodes), block_e=block_e)

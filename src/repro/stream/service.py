@@ -6,12 +6,13 @@ eigenvector panel, and advances them with BATCHED jitted ticks built by
 solver, the warm reconvergence path, and the distributed solves:
 
   * Sessions are grouped by CAPACITY CLASS — (node_cap, edge_cap) — plus
-    their scheduled dilation DEGREE (and, on pallas, the node-blocking
-    layout), and every group tick is ONE compiled `SolveProgram`
-    invocation over the group's stacked edge buffers and panels.  Shapes
-    never depend on a session's live edge count or real node count, so
-    admitting graph #9 to a class that already ticked reuses the
-    compiled step (no per-session recompilation).  Groups are padded to
+    their scheduled dilation DEGREE (and the layout statics of the
+    service's one operator source, stream.source), and every group tick
+    is ONE compiled `SolveProgram` invocation over the group's stacked
+    edge buffers or layouts and panels.  Shapes never depend on a
+    session's live edge count or real node count, so admitting graph #9
+    to a class that already ticked reuses the compiled step (no
+    per-session recompilation).  Groups are padded to
     power-of-two occupancy of their ACTIVE (unconverged) members, so the
     compiled-program set stays logarithmic while converged sessions cost
     ZERO device work per tick.
@@ -77,6 +78,7 @@ from repro.kernels.edge_spmm import ops as es_ops
 from repro.spectral import plan as plan_mod
 from repro.spectral import probes as spectral_probes
 from repro.stream import graph_store as gs
+from repro.stream import source as source_mod
 from repro.stream import tracking, updates
 
 
@@ -175,14 +177,14 @@ class ServiceConfig:
     # Matvec backend for tick programs and probes (repro.core.backend):
     # "auto" = pallas on TPU, segment elsewhere.  Pallas ticks run the
     # node-blocked incidence-SpMM kernel with the dilation step fused
-    # into its epilogue; the per-session blocking is built on admission
-    # and rebuilt after edge updates (graph_store.node_blocking), and
-    # sessions group by (capacity class, degree, blocking layout) — the
-    # chunk count is snapped (es_ops.snap_chunk_count), so compile
-    # counts stay logarithmic.
+    # into its epilogue over a per-session layout (stream.source) built
+    # on the first tick and rebuilt after edge updates; sessions group
+    # by (capacity class, degree, layout statics) — the chunk count is
+    # snapped (es_ops.snap_chunk_count), so compile counts stay
+    # logarithmic.
     backend: str = "auto"
     tick_block_n: int = 512  # node-block rows per VMEM panel slice
-    # Device mesh for SHARDED serving (core.program sharded builders):
+    # Device mesh for SHARDED serving (stream.source, core.program):
     # when set, every group tick runs as one shard_mapped fused series
     # program with the group's edge buffers (segment) or per-shard node
     # blockings (pallas) partitioned over `edge_axes`, one psum of the
@@ -195,9 +197,9 @@ class ServiceConfig:
     # PANEL sharding (core.program.build_tick_model_sharded): when set
     # (with a mesh), every group tick shards the (n, k) panel itself
     # over these mesh axes — shard s owns rows [s*R, (s+1)*R) and the
-    # destination-aligned half-edges landing there
-    # (graph_store.model_sharded_blocking) — and mu-EG solver steps ship
-    # their row assembly and 2k x 2k gram in ONE fused collective.  This
+    # destination-aligned half-edges landing there (stream.source's
+    # panel-sharded layout) — and mu-EG solver steps ship their row
+    # assembly and 2k x 2k gram in ONE fused collective.  This
     # is the million-node serving mode: no device ever materializes
     # per-shard panel copies of the edge buffer, and admission probes
     # route through the same row-sharded matvec.  None = replicated
@@ -215,12 +217,12 @@ class ServiceConfig:
     tick_schedule: str = "residual_decay"
     max_tick_multiplier: int = 8  # cap on the scheduled multiplier
     eval_payoff: float = 2.0  # multiply only past this many plain ticks
-    # Sessions within this factor of tol cap their multiplier at a
-    # gentle 2: the measured decay rate plateaus against the residual
-    # floor near convergence (rate -> 1), so forecasts there are
-    # unreliable in both directions — a full-forecast stretch executes
-    # hundreds of steps for a session one short hop from tolerance,
-    # while plain ticks grind out an invocation per hop.
+    # Sessions within this factor of tol cap their multiplier at 4: the
+    # measured decay rate plateaus against the residual floor near
+    # convergence (rate -> 1), so forecasts there are unreliable in both
+    # directions — a full-forecast stretch executes hundreds of steps
+    # for a session one short hop from tolerance, while plain ticks
+    # grind out an invocation per hop.
     stretch_residual_floor: float = 4.0
 
     def __post_init__(self):
@@ -254,13 +256,9 @@ class _Session:
     lr: float  # per-session step size (traced into the tick program)
     plan_degree: int  # the session's own planned degree suggestion
     tracker: tracking.LabelTracker
-    blocking: es_ops.NodeBlocking | None = None  # pallas tick layout cache
-    # per-shard layout cache for sharded pallas ticks; invalidated
-    # together with `blocking` on edge mutations
-    sharded_blocking: es_ops.ShardedNodeBlocking | None = None
-    # destination-aligned layout cache for PANEL-sharded ticks
-    # (ServiceConfig.model_axes); same invalidation discipline
-    model_blocking: es_ops.ModelShardedBlocking | None = None
+    # the operator source's blocked layout (stream.source), built on the
+    # first tick that reads it, dropped by every edge mutation
+    layout: object | None = None
     group_key: tuple | None = None  # last tick-group key (introspection)
     est: updates.EigenEstimate | None = None
     converged: bool = False
@@ -312,23 +310,8 @@ class StreamingService:
     label serving, eviction."""
 
     def __init__(self, cfg: ServiceConfig = ServiceConfig()):
-        from repro.stream import sharded as sharded_mod
-
         self.cfg = cfg
-        self._backend = backend_mod.resolve_backend(cfg.backend)
-        self._mesh = cfg.mesh
-        # panel sharding is orthogonal to edge sharding: model serving
-        # re-buckets half-edges by destination shard itself, so the
-        # edge-balance contract (and _num_shards) stays on edge_axes
-        self._model_axes = (tuple(cfg.model_axes)
-                            if cfg.mesh is not None
-                            and cfg.model_axes is not None else None)
-        self._model_shards = (
-            program.num_model_shards(cfg.mesh, self._model_axes)
-            if self._model_axes is not None else 1)
-        self._num_shards = (
-            sharded_mod.num_edge_shards(cfg.mesh, cfg.edge_axes)
-            if cfg.mesh is not None else 1)
+        self._source = source_mod.OperatorSource(cfg)
         self._sessions: dict[str, _Session] = {}
         self._compiled: dict[tuple, object] = {}
         self._admitted = 0
@@ -360,14 +343,6 @@ class StreamingService:
     def session_ids(self) -> list[str]:
         return list(self._sessions)
 
-    def _balanced(self, capacity: int) -> int:
-        """Edge capacity rounded up to a shard-balanced size."""
-        from repro.stream import sharded as sharded_mod
-
-        if self._num_shards <= 1:
-            return capacity
-        return sharded_mod.balanced_capacity(capacity, self._num_shards)
-
     # ------------------------------------------------------------------
     # spectral probing + dilation planning
     # ------------------------------------------------------------------
@@ -394,53 +369,7 @@ class StreamingService:
             self._probes_run += 1
             probe_key = jax.random.fold_in(
                 jax.random.PRNGKey(cfg.seed + 7), self._probes_run)
-            if self._model_axes is not None:
-                # Panel-sharded serving probes through the row-sharded
-                # matvec (owned rows per shard, one psum assembly) —
-                # the same decomposition the model tick runs.  The
-                # probe-time blocking is throwaway (the session builds
-                # its own on first tick); probes only run on admission
-                # and drift re-solves, so the host-side rebucket is off
-                # the tick path.
-                mb = gs.model_sharded_blocking(
-                    store, self._model_shards,
-                    block_n=cfg.tick_block_n)
-                probe = spectral_probes.probe_model_sharded(
-                    self._mesh, mb, probe_key,
-                    jnp.asarray(n, jnp.int32),
-                    model_axes=self._model_axes,
-                    num_probes=cfg.probe_vectors,
-                    num_steps=cfg.probe_steps,
-                    backend=self._backend,
-                )
-            elif self._mesh is not None:
-                # Sharded serving probes through the SAME psum-assembled
-                # matvec the tick programs run, so the rho anchoring the
-                # per-session dilation rescale is measured per shard and
-                # agrees with single-device serving up to collective
-                # summation order.
-                probe = spectral_probes.probe_sharded_edge_arrays(
-                    self._mesh, store.src, store.dst, store.weight,
-                    probe_key, jnp.asarray(n, jnp.int32),
-                    num_nodes=store.num_nodes,
-                    edge_axes=cfg.edge_axes,
-                    num_probes=cfg.probe_vectors,
-                    num_steps=cfg.probe_steps,
-                    backend=self._backend,
-                )
-            else:
-                probe = spectral_probes.probe_edge_arrays(
-                    store.src, store.dst, store.weight, probe_key,
-                    jnp.asarray(n, jnp.int32),
-                    num_nodes=store.num_nodes,
-                    num_probes=cfg.probe_vectors,
-                    # NOT clamped to n: probe_steps is jit-static, and
-                    # the Lanczos recurrence handles m >= n via sticky
-                    # breakdown, so the compile stays shared across the
-                    # capacity class.
-                    num_steps=cfg.probe_steps,
-                    backend=self._backend,
-                )
+            probe = self._source.probe(store, probe_key, n)
             est = float(probe.lambda_max)
             if np.isfinite(est) and est > 0.0:
                 rho = min(est, rho_ub)
@@ -528,7 +457,7 @@ class StreamingService:
         cap = (gs.capacity_class(g.num_edges) if edge_capacity is None
                else edge_capacity)
         with tracing.span("service.store"):
-            store = gs.from_edge_list(g, capacity=self._balanced(cap),
+            store = gs.from_edge_list(g, capacity=self._source.balance(cap),
                                       num_nodes=node_cap)
         store, rho, rho_ub, lam_k, lam_k1 = self._rho_estimate(
             store, g.num_nodes)
@@ -617,8 +546,8 @@ class StreamingService:
             # tick joins a different group.  Sharded serving keeps the
             # grown capacity a multiple of the shard count.
             base = gs.grow(base)
-            if base.capacity != self._balanced(base.capacity):
-                base = gs.grow(base, self._balanced(base.capacity))
+            if base.capacity != self._source.balance(base.capacity):
+                base = gs.grow(base, self._source.balance(base.capacity))
             store, dw, stats = gs.apply_edge_batch(base, batch, mode=mode)
         # Ordinary batches rescale cheaply: track the probed estimate by
         # the Gershgorin bound's relative change (no probe matvecs), cap
@@ -627,13 +556,10 @@ class StreamingService:
         store, rho_ub = gs.spectral_radius_upper_bound(store)
         rho_ub_new = float(rho_ub)
         sess.store = store
-        # edge mutation stales the blocked layouts (single, sharded,
-        # and model-sharded), the measured residual-decay rate
-        # (operator changed), and — when the buffer grew a capacity
-        # class — the degree map
-        sess.blocking = None
-        sess.sharded_blocking = None
-        sess.model_blocking = None
+        # edge mutation stales the blocked layout, the measured
+        # residual-decay rate (operator changed), and — when the buffer
+        # grew a capacity class — the degree map
+        sess.layout = None
         sess.rate = None
         self._class_degree_cache = None
         if sess.rho_ub > 0.0:
@@ -755,58 +681,30 @@ class StreamingService:
 
     def _ensure_blocking(self, sess: _Session) -> None:
         """Build (or rebuild after updates) the session's blocked
-        layout for its tick path — host-side, cached on the session.
-        Edge-sharded serving builds the per-shard variant; panel
-        sharding builds the destination-aligned model layout (used by
-        BOTH backends — the model tick's segment path scatters over the
-        same per-shard arrays the kernel consumes)."""
-        block_n = self.cfg.tick_block_n
-        if self._model_axes is not None:
-            attr, build = "model_blocking", lambda: gs.model_sharded_blocking(
-                sess.store, self._model_shards, block_n=block_n)
-        elif self._mesh is not None:
-            attr, build = "sharded_blocking", lambda: gs.sharded_node_blocking(
-                sess.store, self._num_shards, block_n=block_n)
-        else:
-            attr, build = "blocking", lambda: gs.node_blocking(
-                sess.store, block_n=block_n)
-        if getattr(sess, attr) is None:
+        layout — host-side, cached on the session."""
+        if sess.layout is None:
             with tracing.span("service.layout"):
-                b = build()
-                setattr(sess, attr, b)
+                sess.layout = self._source.build(sess.store)
             # the builder pulled the weights to the host: no device read
             live = np.count_nonzero(np.asarray(sess.store.weight))
             self._layout_half_edges += 2 * int(live)
-            self._layout_slots += b.padded_half_edges
+            self._layout_slots += sess.layout.padded_half_edges
 
     def _group_key(self, sess: _Session, degrees: dict | None = None
                    ) -> tuple:
         """Sessions sharing a group share one compiled tick program.
 
-        Groups by capacity class + scheduled dilation degree; pallas
-        additionally groups by the blocking's static layout (block size
-        and snapped chunk count), since those are the shapes the
-        kernel compiles against — sharded pallas uses the per-shard
-        layout's statics the same way.  Only ACTIVE (unconverged)
-        sessions are ever grouped, so a converged session's invalidated
-        blocking is never rebuilt just to anchor a bucket.
+        Groups by capacity class + scheduled dilation degree, plus the
+        source's layout statics (block size and snapped chunk count)
+        when its tick reads a blocked layout: the shapes the kernel
+        compiles against.  Only ACTIVE (unconverged) sessions are ever
+        grouped, so a converged session's invalidated layout is never
+        rebuilt just to anchor a bucket.
         """
-        deg = self._session_degree(sess, degrees)
-        if self._model_axes is not None:
-            # panel sharding needs the layout statics on BOTH backends
-            # (the model tick's segment path runs over the same arrays)
+        if self._source.blocked:
             self._ensure_blocking(sess)
-            b = sess.model_blocking
-            key = (self._class_key(sess), deg, b.block_n,
-                   b.num_chunks, b.block_e)
-        elif self._backend == "pallas":
-            self._ensure_blocking(sess)
-            b = (sess.sharded_blocking if self._mesh is not None
-                 else sess.blocking)
-            key = (self._class_key(sess), deg, b.block_n,
-                   b.num_chunks, b.block_e)
-        else:
-            key = (self._class_key(sess), deg)
+        key = ((self._class_key(sess), self._session_degree(sess, degrees))
+               + self._source.statics(sess.layout))
         sess.group_key = key
         return key
 
@@ -818,13 +716,9 @@ class StreamingService:
             # the per-session learning rates as a traced input
             schedule = program.StepSchedule(
                 method=cfg.method, degree=key[1],
-                steps=cfg.steps_per_tick, backend=self._backend)
-            has_layout = (self._backend == "pallas"
-                          or self._model_axes is not None)
-            layout = key[2:] if has_layout else None
+                steps=cfg.steps_per_tick, backend=self._source.backend)
             fn = program.build_tick_program(
-                schedule, layout=layout, mesh=self._mesh,
-                edge_axes=cfg.edge_axes, model_axes=self._model_axes)
+                schedule, **self._source.tick_args(key[2:]))
             self._compiled[(key, occupancy)] = fn
         return fn
 
@@ -916,33 +810,16 @@ class StreamingService:
         occ = _next_pow2(len(members))
         step = self._get_step(gkey, occ)
         idx = list(range(len(members))) + [0] * (occ - len(members))
-        stack = lambda f: jnp.stack([f(members[i]) for i in idx])
         cs = jnp.asarray(
             [program.dilation_scale(members[i].plan, gkey[1])
              for i in idx], jnp.float32)
         lrs = jnp.asarray([members[i].lr for i in idx], jnp.float32)
         # traced per-session chunk budgets: no recompile for any mix
         chunks = jnp.asarray(mults[np.asarray(idx)], jnp.int32)
-        if self._model_axes is not None:
-            layout = lambda s: s.model_blocking
-        elif self._backend == "pallas" and self._mesh is not None:
-            layout = lambda s: s.sharded_blocking
-        elif self._backend == "pallas":
-            layout = lambda s: s.blocking
-        else:
-            layout = None
-        if layout is None:
-            # single-device segment AND sharded segment take the same
-            # stacked-edge-buffer signature (the sharded builder shards
-            # the capacity axis over the mesh)
-            edges = [stack(lambda s: s.store.src),
-                     stack(lambda s: s.store.dst),
-                     stack(lambda s: s.store.weight)]
-        else:
-            edges = [stack(lambda s, f=f: getattr(layout(s), f))
-                     for f in ("u_local", "other", "weight", "chunk_block",
-                               "deg")]
-        return step, occ, (*edges, stack(lambda s: s.v), cs, lrs, chunks)
+        ops = [jnp.stack(a) for a in zip(
+            *(self._source.operands(members[i]) for i in idx))]
+        vs = jnp.stack([members[i].v for i in idx])
+        return step, occ, (*ops, vs, cs, lrs, chunks)
 
     def tick(self) -> dict[str, float]:
         """Advance every unconverged session one scheduled tick — one
@@ -1009,7 +886,7 @@ class StreamingService:
     def backend(self) -> str:
         """The resolved SpMM backend the tick runs ("pallas" or
         "segment")."""
-        return self._backend
+        return self._source.backend
 
     def run_until_converged(self, max_ticks: int = 500) -> int:
         """Tick until every session converges; returns ticks used.

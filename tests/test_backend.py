@@ -258,7 +258,7 @@ def test_streaming_tick_backend_equivalence():
     for svc in (seg, pal):
         svc.apply_updates("a", [[0, 5], [1, 7]], [1.0, 1.0])
     seg.tick(), pal.tick()
-    assert pal._sessions["a"].blocking is not None
+    assert pal._sessions["a"].layout is not None
     vs = seg._sessions["a"].v
     vp = pal._sessions["a"].v
     assert float(jnp.max(jnp.abs(vs - vp))) <= TOL

@@ -380,10 +380,10 @@ def test_sharded_streaming_tick_equivalence(mesh):
     # updates stale the per-shard layouts; ticks stay glued afterwards
     for svc in svcs:
         svc.apply_updates("weighted", [[0, 5], [1, 7]], [1.0, 1.0])
-    assert shard_pal._sessions["weighted"].sharded_blocking is None
+    assert shard_pal._sessions["weighted"].layout is None
     for svc in svcs:
         svc.tick()
-    assert shard_pal._sessions["weighted"].sharded_blocking is not None
+    assert shard_pal._sessions["weighted"].layout is not None
     for svc in svcs[1:]:
         err = float(jnp.max(jnp.abs(
             svc._sessions["weighted"].v - single._sessions["weighted"].v)))
@@ -523,10 +523,10 @@ def test_service_model_sharded_tick_equivalence():
     # updates stale the destination-aligned layouts; ticks re-glue
     for svc in svcs:
         svc.apply_updates("weighted", [[0, 5], [1, 7]], [1.0, 1.0])
-    assert sharded[0]._sessions["weighted"].model_blocking is None
+    assert sharded[0]._sessions["weighted"].layout is None
     for svc in svcs:
         svc.tick()
-    assert sharded[0]._sessions["weighted"].model_blocking is not None
+    assert sharded[0]._sessions["weighted"].layout is not None
     for svc in svcs[1:]:
         err = float(np.max(np.abs(
             np.asarray(svc._sessions["weighted"].v)
@@ -571,7 +571,7 @@ def test_million_node_model_sharded_tick():
     assert np.isfinite(v[:n]).all()
     # the layout really shards: every shard owns rows, and the skewed
     # half-edge mass spreads without any per-shard edge-balance contract
-    mb = sess.model_blocking
+    mb = sess.layout
     assert mb.num_shards == num_shards
     assert mb.rows_per_shard * num_shards >= n
     assert mb.padded_half_edges >= 2 * g.num_edges

@@ -188,7 +188,7 @@ def test_server_stats_layout_pad_share():
             break
     assert srv.service.all_converged
     share = srv.stats()["engine"]["layout_pad_share"]
-    slots = srv.service._sessions["a"].blocking.padded_half_edges
+    slots = srv.service._sessions["a"].layout.padded_half_edges
     assert 0.0 < share < 1.0
     assert share == pytest.approx(1.0 - 2 * len(edges) / slots)
 
@@ -205,7 +205,7 @@ def test_server_stats_scatter_passes(k):
     edges, w, n, _ = _sbm_edges(13, n=64)
     srv.admit("a", edges, n, weights=w, num_clusters=3, edge_capacity=1024)
     assert srv.step()
-    assert srv.service._sessions["a"].blocking is not None
+    assert srv.service._sessions["a"].layout is not None
     assert srv.stats()["engine"]["scatter_passes"] == 1
     seg = Server(ServerConfig(service=dataclasses.replace(
         SERVE_SVC, backend="segment", k=k)))

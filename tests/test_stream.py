@@ -432,6 +432,55 @@ def test_edgeless_admission_recovers_after_updates(backend):
     assert bool(jnp.all(jnp.isfinite(sess.v)))
 
 
+@pytest.mark.parametrize("backend", ["segment", "pallas"])
+def test_operator_source_feeds_the_tick_program(backend):
+    """The service's one operator source gives the group key's layout
+    statics and the operands that ``program.build_tick_program(
+    **tick_args)`` takes, and that program ticks like the service; an
+    edge batch drops the session's one layout, and the next tick
+    rebuilds it where the tick reads one."""
+    from repro.core import program
+
+    svc = StreamingService(dataclasses.replace(
+        SVC_CFG, steps_per_tick=2, backend=backend, tick_block_n=32,
+        tick_schedule="round_robin"))
+    g, _ = graphs.ring_of_cliques(3, 6)
+    svc.add_graph("a", g, num_clusters=3)
+    source, sess = svc._source, svc._sessions["a"]
+    blocked = backend == "pallas"
+    assert source.blocked == blocked
+    svc.tick()
+    assert (sess.layout is not None) == blocked
+    key = sess.group_key
+    assert len(key) == (5 if blocked else 2)
+    assert key[2:] == source.statics(sess.layout)
+    ops = source.operands(sess)
+    i32, f32 = jnp.dtype(jnp.int32), jnp.dtype(jnp.float32)
+    assert [o.dtype for o in ops] == (
+        [i32, i32, f32, i32, f32] if blocked else [i32, i32, f32])
+    tick = program.build_tick_program(
+        program.StepSchedule(method=svc.cfg.method, degree=key[1],
+                             steps=svc.cfg.steps_per_tick,
+                             backend=svc.backend),
+        **source.tick_args(key[2:]))
+    vs, res = tick(*(o[None] for o in ops), sess.v[None],
+                   jnp.asarray([program.dilation_scale(sess.plan, key[1])],
+                               jnp.float32),
+                   jnp.asarray([sess.lr], jnp.float32),
+                   jnp.ones((1,), jnp.int32))
+    assert not sess.converged
+    want = svc.tick()["a"]
+    np.testing.assert_allclose(np.asarray(vs[0]), np.asarray(sess.v),
+                               atol=1e-6)
+    np.testing.assert_allclose(float(res[0]), want, rtol=1e-5)
+    svc.apply_updates("a", [[0, 7]], [1.0])
+    assert sess.layout is None
+    assert not sess.converged
+    svc.tick()
+    assert (sess.layout is not None) == blocked
+    assert svc.compile_count == 1
+
+
 # ---------------------------------------------------------------------------
 # typed session errors, converged re-entry, per-session multipliers
 # ---------------------------------------------------------------------------
